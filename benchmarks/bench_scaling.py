@@ -71,6 +71,8 @@ def run(device_counts=(1, 2, 4, 8)):
     for nd in device_counts:
         env = dict(env_base)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={nd}"
+        # virtual CPU devices; the parent may hold the chip
+        env["JAX_PLATFORMS"] = "cpu"
         p = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                            capture_output=True, text=True, timeout=900)
         if p.returncode != 0:

@@ -7,6 +7,8 @@ with build overrides (the hillclimb knobs).
 """
 import os
 
+# a CPU study on 512 virtual devices: it must never take the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 import argparse
@@ -18,9 +20,11 @@ def run(arch: str, shape: str, label: str = "baseline", profile: bool = False,
         out_path: str = "results/perf_lm.json", **overrides):
     from repro.configs import get_arch
     from repro.launch.mesh import make_production_mesh
-    from repro.roofline.analysis import analyze_compiled
+    from repro.roofline.analysis import DRYRUN_TARGET_KIND, analyze_compiled
     from repro.roofline import hlo_profile
 
+    print(f"roofline terms below are compile-time estimates from a CPU "
+          f"compile against {DRYRUN_TARGET_KIND} peaks; not measured")
     mesh = make_production_mesh()
     spec = get_arch(arch)
     t0 = time.perf_counter()
@@ -31,7 +35,8 @@ def run(arch: str, shape: str, label: str = "baseline", profile: bool = False,
     compiled = lowered.compile()
     t2 = time.perf_counter()
     txt = compiled.as_text()
-    r = analyze_compiled(compiled, mesh, arch=arch, shape=shape)
+    r = analyze_compiled(compiled, mesh, device_kind=DRYRUN_TARGET_KIND,
+                         arch=arch, shape=shape)
     r["label"] = label
     r["lower_s"] = round(t1 - t0, 1)
     r["compile_s"] = round(t2 - t1, 1)

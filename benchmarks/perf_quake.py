@@ -18,6 +18,8 @@ Ladder:
 """
 import os
 
+# a CPU study on 512 virtual devices: it must never take the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 import argparse
@@ -35,8 +37,11 @@ def run(shape: str, variants=None, out_path="results/perf_quake.json"):
     import jax
     from repro.configs.quake_arch import build_quake, FULL, QUAKE_SHAPES
     from repro.launch.mesh import make_production_mesh
-    from repro.roofline.analysis import analyze_compiled
+    from repro.roofline.analysis import (DRYRUN_TARGET_KIND,
+                                         analyze_compiled, peaks)
 
+    print(f"roofline terms below are compile-time estimates from a CPU "
+          f"compile against {DRYRUN_TARGET_KIND} peaks; not measured")
     mesh = make_production_mesh()
     sh = QUAKE_SHAPES[shape]
     b = sh.get("batch", 1024)
@@ -73,7 +78,8 @@ def run(shape: str, variants=None, out_path="results/perf_quake.json"):
         t1 = time.perf_counter()
         compiled = lowered.compile()
         t2 = time.perf_counter()
-        r = analyze_compiled(compiled, mesh, arch="quake-ann", shape=shape)
+        r = analyze_compiled(compiled, mesh, device_kind=DRYRUN_TARGET_KIND,
+                             arch="quake-ann", shape=shape)
         r["lower_s"] = round(t1 - t0, 1)
         r["compile_s"] = round(t2 - t1, 1)
         r["variant"] = name
@@ -91,7 +97,8 @@ def run(shape: str, variants=None, out_path="results/perf_quake.json"):
                       + b_loc * (d + 2 * u) * 4    # queries + qmask + qc
                       + 2 * b_loc * 128 * 8)       # top-k out
             r["tpu_native_bytes_gb"] = round(native / 1e9, 4)
-            r["tpu_native_t_memory_ms"] = round(native / 819e9 * 1e3, 4)
+            r["tpu_native_t_memory_ms"] = round(
+                native / peaks(DRYRUN_TARGET_KIND)["hbm_bw"] * 1e3, 4)
         results[name] = r
         print(f"{name:>13}: t_comp {r['t_compute_ms']:.3f}ms  "
               f"t_mem {r['t_memory_ms']:.3f}ms  "

@@ -20,6 +20,9 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
+
     from . import (bench_aps_variants, bench_early_termination,
                    bench_maintenance, bench_multilevel, bench_multiquery,
                    bench_scaling, bench_workloads)

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 from ..models import gnn, recsys, transformer as tr
 from ..train import optimizer as opt, steps
 from .base import SDS, Lowering, dp_axes_for, named_sharding_tree

@@ -45,8 +45,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
-from ..kernels.ref import MASK_DIST, merge_topk, pairwise_l2_sq
+from jax import shard_map
+from ..kernels.ref import HIGHEST, MASK_DIST, merge_topk, pairwise_l2_sq
 from . import geometry
 from .index import QuakeIndex
 
@@ -176,8 +176,7 @@ class IndexSnapshot:
         # selects them (MASK via sizes==0 also applies)
         if p > p_real:
             cents[p_real:] = 1e6
-        table = geometry.betainc_table(
-            d if index.config.metric == "l2" else d + 1)
+        table = index._beta_table     # the index's (fitted) cap model
         return IndexSnapshot(
             data=jnp.asarray(data), ids=jnp.asarray(ids),
             centroids=jnp.asarray(cents), sizes=jnp.asarray(sizes),
@@ -378,6 +377,8 @@ class ShardedQuakeEngine:
         structural changes, int8 storage (rows would need requantizing),
         capacity overflow, or a trimmed journal re-shard a full rebuild.
         """
+        from .maintenance import fit_to_capacity  # late: import cycle
+        fixed = fit_to_capacity(index, index.config.snapshot_headroom)
         if self._snap is not None and self.cfg.storage_dtype != "int8":
             delta = index.journal.delta_since(self._snap_version)
             if delta is not None and not delta.structural:
@@ -407,7 +408,7 @@ class ShardedQuakeEngine:
                         self.delta_refreshes += 1
                         return self._snap
         host = IndexSnapshot.from_index(
-            index, pad_partitions_to=self.n_part_shards,
+            index, pad_partitions_to=self.n_part_shards, capacity=fixed,
             headroom=index.config.snapshot_headroom)
         self._snap = self.shard_snapshot(host)
         self._host_sizes = np.array(host.sizes)
@@ -434,7 +435,7 @@ class ShardedQuakeEngine:
         if self.cfg.metric == "l2":
             d = pairwise_l2_sq(q, snap.centroids)
         else:
-            d = -(q @ snap.centroids.T)
+            d = -jnp.matmul(q, snap.centroids.T, precision=HIGHEST)
         return jnp.where(snap.sizes[None, :] > 0, d, MASK_DIST)
 
     def _scan_selected(self, q: Array, snap: IndexSnapshot,
@@ -452,12 +453,14 @@ class ShardedQuakeEngine:
         if self.cfg.metric == "l2":
             x2 = jnp.sum(blocks32 * blocks32, axis=-1)
             qx = jnp.einsum("bnsd,bd->bns", blocks32, q,
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=HIGHEST)
             q2 = jnp.sum(q * q, axis=-1)[:, None, None]
             dist = x2 - 2.0 * qx + q2
         else:
             dist = -jnp.einsum("bnsd,bd->bns", blocks32, q,
-                               preferred_element_type=jnp.float32)
+                               preferred_element_type=jnp.float32,
+                               precision=HIGHEST)
         dist = jnp.where(valid, dist, MASK_DIST)
         b = dist.shape[0]
         return dist.reshape(b, -1), bids.reshape(b, -1)
@@ -572,7 +575,8 @@ class ShardedQuakeEngine:
         # tie-break: normalize so exactly weight-1 total across all shards
         w = is_min / jnp.maximum(jax.lax.psum(
             jnp.sum(is_min, axis=1), axes), 1.0)[:, None]
-        c0 = jax.lax.psum(w @ snap.centroids, axes)      # (B, d)
+        c0 = jax.lax.psum(jnp.matmul(w, snap.centroids, precision=HIGHEST),
+                          axes)                          # (B, d)
         cc = jnp.sqrt(jnp.maximum(pairwise_l2_sq(c0, snap.centroids), 1e-12))
 
         def probs(rho_sq: Array, scanned: Array) -> Tuple[Array, Array]:
@@ -652,7 +656,7 @@ class ShardedQuakeEngine:
         if cfg.metric == "l2":
             dist = pairwise_l2_sq(q, flat)
         else:
-            dist = -(q @ flat.T)
+            dist = -jnp.matmul(q, flat.T, precision=HIGHEST)
         dist = jnp.where(fids[None, :] >= 0, dist, MASK_DIST)
         k = min(cfg.k, dist.shape[1])
         vals, pos = jax.lax.top_k(-dist, k)

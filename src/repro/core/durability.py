@@ -438,6 +438,8 @@ def write_checkpoint(index: QuakeIndex, root: str, generation: int,
         "dim": int(index.dim),
         "max_norm_sq": float(index._max_norm_sq),
         "config": dataclasses.asdict(index.config),
+        "aps_model": {"f_m": index.aps_f_m,
+                      "geometry_dim": index.geometry_dim},
         "levels": levels_desc,
         "meta": meta_name,
         "partitions": part_names,
@@ -555,6 +557,8 @@ def load_checkpoint(gendir: str, manifest: dict) -> QuakeIndex:
     idx.levels = levels
     idx._aug_extra = [None] * len(levels)
     idx._max_norm_sq = float(manifest["max_norm_sq"])
+    if "aps_model" in manifest:
+        idx.set_aps_model(**manifest["aps_model"])
     for j, ids in enumerate(lvl0.ids):
         for ext in ids:
             idx.id_map[int(ext)] = j

@@ -72,6 +72,13 @@ class QuakeConfig:
     snapshot_headroom: float = 1.5      # slack factor on snapshot slot
                                         # capacity so insert deltas rarely
                                         # force a full reshape/rebuild
+    snapshot_capacity: Optional[int] = None  # fixed slot capacity of the
+                                        # device snapshot, for one that must
+                                        # stay within device memory: a
+                                        # partition that outgrows it is
+                                        # split to capacity / headroom
+                                        # (maintenance.split_to_fit).  None:
+                                        # slots follow the largest partition
     snapshot_max_dirty_frac: float = 0.5  # delta-refresh only while dirty
                                         # partitions <= frac * P; beyond
                                         # that a full rebuild is cheaper
@@ -150,8 +157,15 @@ class QuakeIndex:
                                              # structural flags; snapshot
                                              # caches consume deltas from it
         self._rng = np.random.default_rng(self.config.seed)
-        self.geometry_dim = dim if self.config.metric == "l2" else dim + 1
+        # APS's cap model: the dimension of its geometry and the fraction
+        # f_M of nearest partitions it considers.  They start at the
+        # paper's (the data's dimension, config.f_m); calibrate_aps fits
+        # them to the data
+        self.model_dim = dim if self.config.metric == "l2" else dim + 1
+        self.geometry_dim = self.model_dim
         self._beta_table = geometry.betainc_table(self.geometry_dim)
+        self.aps_f_m = self.config.f_m
+        self.aps_calibration: dict = {}
         self._max_norm_sq = 1e-12           # MIPS augmentation constant M^2
         self._aug_extra: List[Optional[np.ndarray]] = []  # per level cache
         self.maintenance_log: List[dict] = []
@@ -200,6 +214,7 @@ class QuakeIndex:
         for p_l in level_sizes[1:]:
             idx._add_level_from(p_l, kmeans_iters)
         idx._aug_extra = [None] * len(idx.levels)
+        idx.calibrate_aps()
         return idx
 
     def _add_level_from(self, p_l: int, iters: int = 10) -> None:
@@ -342,10 +357,10 @@ class QuakeIndex:
         for l in range(L - 1, -1, -1):
             level = self.levels[l]
             if l == 0:
-                k_l, tgt, f_m = k, target, cfg.f_m
+                k_l, tgt, f_m = k, target, self.aps_f_m
             else:
                 below_n = self.levels[l - 1].num_partitions
-                f_m_below = cfg.f_m if l - 1 == 0 else cfg.f_m_upper
+                f_m_below = self.aps_f_m if l - 1 == 0 else cfg.f_m_upper
                 # APS at level l must find, with high recall, the candidates
                 # the level below will consider:
                 k_l = max(k, int(math.ceil(f_m_below * below_n)))
@@ -452,6 +467,129 @@ class QuakeIndex:
         return aps_mod.APSResult(ids=heap.ids, dists=heap.dists,
                                  scanned=np.asarray(order),
                                  nprobe=len(order), recall_estimate=np.nan)
+
+    # ------------------------------------------------------------------
+    # APS calibration
+    # ------------------------------------------------------------------
+
+    _CALIB_QUERIES = 64    # leave-one-out sample of resident vectors
+    _CALIB_K = 10          # neighbours the calibration counts
+    _CALIB_DIMS = (256, 64, 16, 4)
+
+    def set_aps_model(self, f_m: float, geometry_dim: int) -> None:
+        """Set APS's candidate fraction and cap dimension (what
+        ``calibrate_aps`` fits; checkpoints restore it)."""
+        self.aps_f_m = float(f_m)
+        self.geometry_dim = int(geometry_dim)
+        self._beta_table = geometry.betainc_table(self.geometry_dim)
+
+    def calibrate_aps(self) -> dict:
+        """Fit APS's cap model to this index's data.
+
+        The model rates a candidate partition by the share of the query
+        ball beyond its bisector, in ``geometry_dim`` dimensions, over the
+        ``f_M * P`` nearest partitions.  Where a query's neighbours spread
+        over many partitions (topics of isotropic noise at d=768) both
+        starve APS: the share falls off as ``(1 - (h/rho)^2)^(d/2)``, the
+        estimate passes the target after a few probes, and the recall is
+        far below it.  Calibration takes ``_CALIB_QUERIES`` resident
+        vectors as leave-one-out queries, finds their exact ``_CALIB_K``
+        nearest neighbours, and keeps the first
+        setting whose mean recall meets ``recall_target``: the unfitted
+        model, else f_M doubling towards 1 and, for the first f_M that
+        can reach the target, the cap dimension falling from the data's
+        towards 4.  Returns (and keeps in ``aps_calibration``) what it
+        measured; an index too small to sample keeps the unfitted model.
+        """
+        cfg = self.config
+        nq, k = self._CALIB_QUERIES, self._CALIB_K
+        lvl0 = self.levels[0]
+        sizes = lvl0.sizes()
+        n = int(sizes.sum())
+        if n <= nq + k or not cfg.enable_aps:
+            return {}
+        rng = np.random.default_rng(cfg.seed)
+        flat = np.sort(rng.choice(n, nq, replace=False))
+        starts = np.cumsum(sizes) - sizes
+        part = np.searchsorted(starts, flat, side="right") - 1
+        q = np.stack([lvl0.vectors[p][f - starts[p]]
+                      for p, f in zip(part, flat)])
+        self_ids = np.asarray([lvl0.ids[p][f - starts[p]]
+                               for p, f in zip(part, flat)])
+        # exact top-(k+1) over every partition, streamed
+        best_d = np.full((nq, 0), np.inf)
+        best_i = np.full((nq, 0), -1, dtype=np.int64)
+        for j in range(lvl0.num_partitions):
+            if not sizes[j]:
+                continue
+            d = -(q @ lvl0.vectors[j].T).astype(np.float64)
+            if cfg.metric == "l2":
+                d = 2.0 * d + lvl0.sqnorms[j][None, :]
+            d = np.concatenate([best_d, d], axis=1)
+            i = np.concatenate([best_i, np.broadcast_to(
+                lvl0.ids[j], (nq, len(lvl0.ids[j])))], axis=1)
+            keep = np.argsort(d, axis=1, kind="stable")[:, :k + 1]
+            best_d = np.take_along_axis(d, keep, axis=1)
+            best_i = np.take_along_axis(i, keep, axis=1)
+        truth = [set([i for i in row if i != s][:k])
+                 for row, s in zip(best_i.tolist(), self_ids.tolist())]
+
+        before = (self.aps_f_m, self.geometry_dim)
+        tried = []
+
+        def recall_at(f_m: float, gdim: int) -> float:
+            self.set_aps_model(f_m, gdim)
+            hits = 0
+            for x, s, t in zip(q, self_ids.tolist(), truth):
+                r = self.search(x, k + 1, record_stats=False)
+                got = [i for i in r.ids.tolist() if i != s][:k]
+                hits += len(t.intersection(got))
+            rec = hits / (k * nq)
+            tried.append((f_m, gdim, rec))
+            return rec
+
+        target = cfg.recall_target
+        dims = [self.model_dim] + [g for g in self._CALIB_DIMS
+                                   if 2 * g <= self.model_dim]
+        # f_M doubling towards 1, each step adding candidates
+        p0 = lvl0.num_partitions
+        f_ladder, f, seen = [], cfg.f_m, set()
+        while True:
+            f = min(f, 1.0)
+            n_cand = min(max(math.ceil(f * p0), cfg.min_candidates), p0)
+            if n_cand not in seen:
+                seen.add(n_cand)
+                f_ladder.append(f)
+            if f >= 1.0:
+                break
+            f *= 2.0
+        chosen = None
+        if recall_at(cfg.f_m, dims[0]) >= target:
+            chosen = (cfg.f_m, dims[0])
+        else:
+            for f in f_ladder:
+                # the flattest model scans the most this f_M allows
+                flat_ok = recall_at(f, dims[-1]) >= target
+                if not flat_ok and f < f_ladder[-1]:
+                    continue
+                chosen = (f, dims[-1])
+                for g in dims[:-1] if flat_ok else ():
+                    if (f, g) != (cfg.f_m, dims[0]) \
+                            and recall_at(f, g) >= target:
+                        chosen = (f, g)
+                        break
+                break
+        self.set_aps_model(*chosen)
+        rec = next(r for f, g, r in reversed(tried) if (f, g) == chosen)
+        self.aps_calibration = {
+            "f_m": self.aps_f_m, "geometry_dim": self.geometry_dim,
+            "recall": rec, "target": target, "met": rec >= target,
+            "queries": nq, "k": k, "tried": tried}
+        if (self.aps_f_m, self.geometry_dim) != before:
+            # planning caches key on the version clock: a new table or
+            # candidate fraction must reach them
+            self.journal.record(reason="aps_calibration")
+        return self.aps_calibration
 
     # ------------------------------------------------------------------
     # Updates (paper §3 Adaptive Incremental Maintenance - data path)

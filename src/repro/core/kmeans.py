@@ -29,14 +29,20 @@ def _next_pow2(n: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("k", "iters"))
-def _lloyd(xp: Array, mask: Array, init_c: Array, k: int, iters: int
-           ) -> Tuple[Array, Array, Array]:
+def _lloyd(xp: Array, mask: Array, init_c: Array, cmask: Array, k: int,
+           iters: int) -> Tuple[Array, Array, Array]:
     """Masked Lloyd iterations.  xp (Np, d) padded points, mask (Np,) bool,
-    init_c (k, d).  Returns (centroids, assign, objective)."""
+    init_c (k, d), cmask (k,) bool marks the real centroids (padding rows
+    never win a point and are never reseeded).  Returns (centroids,
+    assign, objective)."""
+
+    def dists(c):
+        d = pairwise_l2_sq(xp, c)                      # (Np, k)
+        d = jnp.where(cmask[None, :], d, MASK_DIST)
+        return jnp.where(mask[:, None], d, MASK_DIST)
 
     def step(c, _):
-        d = pairwise_l2_sq(xp, c)                      # (Np, k)
-        d = jnp.where(mask[:, None], d, MASK_DIST)
+        d = dists(c)
         assign = jnp.argmin(d, axis=1)
         mind = jnp.min(d, axis=1)
         w = mask.astype(xp.dtype)
@@ -46,14 +52,13 @@ def _lloyd(xp: Array, mask: Array, init_c: Array, k: int, iters: int
                           sums / jnp.maximum(cnts[:, None], 1.0), c)
         # Reseed empties to the currently worst-fit points (masked-valid).
         worst = jnp.argsort(jnp.where(mask, -mind, -0.0))[:k]
-        empty = cnts == 0
+        empty = (cnts == 0) & cmask
         new_c = jnp.where(empty[:, None], xp[worst], new_c)
         obj = jnp.sum(jnp.where(mask, mind, 0.0))
         return new_c, obj
 
     c, objs = jax.lax.scan(step, init_c, None, length=iters)
-    d = pairwise_l2_sq(xp, c)
-    d = jnp.where(mask[:, None], d, MASK_DIST)
+    d = dists(c)
     assign = jnp.argmin(d, axis=1).astype(jnp.int32)
     return c, assign, objs
 
@@ -77,9 +82,11 @@ def kmeans(x: np.ndarray, k: int, iters: int = 10, seed: int = 0,
         init_c = x[rng.choice(n, size=k, replace=False)].astype(np.float32)
 
     c, assign, _ = _lloyd(jnp.asarray(xp), jnp.asarray(mask),
-                          jnp.asarray(init_c), k, iters)
+                          jnp.asarray(init_c), jnp.ones(k, bool), k, iters)
     # np.array (not asarray): jax buffers are read-only; callers mutate.
-    return np.array(c), np.array(assign[:n])
+    # slice on host: slicing the device array would compile a new program
+    # for every distinct n (one per maintenance split on the chip)
+    return np.array(c), np.array(assign)[:n]
 
 
 def _kmeanspp_init(x: np.ndarray, k: int, rng: np.random.Generator
@@ -155,15 +162,20 @@ def refine(parts: list, centroids: np.ndarray, iters: int = 1,
     ids = np.concatenate([p[1] for p in parts], axis=0)
     k, d = centroids.shape
     n = xs.shape[0]
-    npad = _next_pow2(max(n, 8))
+    # the group size varies with every split: pad the centroids to a
+    # multiple of 16 (masked) so the compiled Lloyd step is reused
+    kpad = -(-k // 16) * 16
+    npad = _next_pow2(max(n, kpad))
     xp = np.zeros((npad, d), dtype=np.float32)
     xp[:n] = xs
     mask = np.zeros(npad, dtype=bool)
     mask[:n] = True
-    c, a, _ = _lloyd(jnp.asarray(xp), jnp.asarray(mask),
-                     jnp.asarray(centroids, jnp.float32), k, iters)
-    c = np.array(c)
-    a = np.array(a[:n])
+    cp = np.zeros((kpad, d), dtype=np.float32)
+    cp[:k] = centroids
+    c, a, _ = _lloyd(jnp.asarray(xp), jnp.asarray(mask), jnp.asarray(cp),
+                     jnp.asarray(np.arange(kpad) < k), kpad, iters)
+    c = np.array(c)[:k]
+    a = np.array(a)[:n]
     new_parts = []
     for j in range(k):
         sel = a == j

@@ -112,6 +112,85 @@ class MaintenanceReport:
     actions: List[dict] = field(default_factory=list)
 
 
+def apply_split(idx: QuakeIndex, l: int, j: int, c2: np.ndarray,
+                a2: np.ndarray) -> None:
+    """Split partition ``j`` of level ``l`` by the 2-way assignment ``a2``
+    (centroids ``c2``): side 0 stays at ``j``, side 1 is appended.  The
+    caller records the journal entry."""
+    level = idx.levels[l]
+    new_j = level.num_partitions
+    level.centroids = np.concatenate([level.centroids, c2[1:2]])
+    level.centroids[j] = c2[0]
+    if level.vectors is not None:
+        x, ids_, sq = level.vectors[j], level.ids[j], level.sqnorms[j]
+        keep, move = a2 == 0, a2 == 1
+        level.vectors[j] = np.ascontiguousarray(x[keep])
+        level.ids[j] = ids_[keep]
+        level.sqnorms[j] = sq[keep]
+        level.vectors.append(np.ascontiguousarray(x[move]))
+        level.ids.append(ids_[move])
+        level.sqnorms.append(sq[move])
+        for ext in level.ids[new_j]:
+            idx.id_map[int(ext)] = new_j
+    else:
+        child = level.children[j]
+        level.children[j] = child[a2 == 0]
+        level.children.append(child[a2 == 1])
+        below = idx.levels[l - 1]
+        below.parent[level.children[new_j]] = new_j
+    # stats: children inherit alpha * parent's window hits
+    level.stats.ensure(level.num_partitions - 1)
+    level.stats.split(j, idx.config.alpha)
+    # parent bookkeeping: the new centroid joins j's parent partition
+    if l < len(idx.levels) - 1:
+        p = int(level.parent[j])
+        level.parent = np.append(level.parent, p)
+        up = idx.levels[l + 1]
+        up.children[p] = np.append(up.children[p], new_j)
+    idx._aug_extra = [None] * len(idx.levels)
+
+
+def split_to_fit(idx: QuakeIndex, limit: int) -> int:
+    """Split every base partition of more than ``limit`` vectors by 2-means
+    until each piece fits (a size bound on partitions, as LIRE keeps its
+    posting lists short).  The pieces are appended to the directory and
+    journaled as content changes of the split and the new partitions, so
+    a snapshot with spare partition rows takes them as a patch, at its
+    slot capacity.  APS's model is then refitted to the new partitions,
+    as after a maintenance pass.  Returns the number of splits."""
+    lvl0 = idx.levels[0]
+    limit = max(int(limit), 1)
+    todo = [j for j in range(lvl0.num_partitions)
+            if len(lvl0.vectors[j]) > limit]
+    splits = 0
+    while todo:
+        j = todo.pop()
+        c2, a2 = kmeans.split_two(lvl0.vectors[j], seed=idx.config.seed + j)
+        new_j = lvl0.num_partitions
+        idx.journal.record(dirty=(j, new_j), reason="split_fit")
+        apply_split(idx, 0, j, c2, a2)
+        splits += 1
+        todo.extend(p for p in (j, new_j) if len(lvl0.vectors[p]) > limit)
+    if splits:
+        idx.calibrate_aps()
+    return splits
+
+
+def fit_to_capacity(idx: QuakeIndex, headroom: float) -> Optional[int]:
+    """The slot capacity a snapshot of ``idx`` takes: None (follow the
+    largest partition) unless ``config.snapshot_capacity`` fixes it, in
+    which case partitions larger than it are first split to
+    ``capacity / headroom`` (``split_to_fit``)."""
+    from .distributed import IndexSnapshot  # late: avoid import cycle
+    fixed = idx.config.snapshot_capacity
+    if not fixed:
+        return None
+    cap = IndexSnapshot.align_capacity(fixed)
+    if max(len(v) for v in idx.levels[0].vectors) > cap:
+        split_to_fit(idx, cap / max(headroom, 1.0))
+    return cap
+
+
 class Maintainer:
     """Drives maintenance for a QuakeIndex against a latency model."""
 
@@ -186,6 +265,9 @@ class Maintainer:
         # committed actions themselves (split/merge/refine/level) — a pass
         # where nothing commits leaves the version clock untouched and no
         # consumer rebuilds anything.
+        if idx.version != version_before:
+            # the partitions changed: refit APS's model to them
+            idx.calibrate_aps()
         if reset_stats:
             for level in idx.levels:
                 level.stats.reset()
@@ -311,43 +393,12 @@ class Maintainer:
 
     def _apply_split(self, l: int, j: int, c2: np.ndarray, a2: np.ndarray
                      ) -> None:
-        idx = self.index
         # base-level splits change the partition directory itself:
         # structural for snapshot consumers.  Upper-level splits only touch
         # planning structures — bump the clock, dirty nothing.
-        idx.journal.record(structural=(l == 0),
-                           reason="split" if l == 0 else "split_upper")
-        level = idx.levels[l]
-        new_j = level.num_partitions
-        level.centroids = np.concatenate([level.centroids, c2[1:2]])
-        level.centroids[j] = c2[0]
-        if level.vectors is not None:
-            x, ids_, sq = level.vectors[j], level.ids[j], level.sqnorms[j]
-            keep, move = a2 == 0, a2 == 1
-            level.vectors[j] = np.ascontiguousarray(x[keep])
-            level.ids[j] = ids_[keep]
-            level.sqnorms[j] = sq[keep]
-            level.vectors.append(np.ascontiguousarray(x[move]))
-            level.ids.append(ids_[move])
-            level.sqnorms.append(sq[move])
-            for ext in level.ids[new_j]:
-                idx.id_map[int(ext)] = new_j
-        else:
-            child = level.children[j]
-            level.children[j] = child[a2 == 0]
-            level.children.append(child[a2 == 1])
-            below = idx.levels[l - 1]
-            below.parent[level.children[new_j]] = new_j
-        # stats: children inherit alpha * parent's window hits
-        level.stats.ensure(level.num_partitions - 1)
-        level.stats.split(j, idx.config.alpha)
-        # parent bookkeeping: the new centroid joins j's parent partition
-        if l < len(idx.levels) - 1:
-            p = int(level.parent[j])
-            level.parent = np.append(level.parent, p)
-            up = idx.levels[l + 1]
-            up.children[p] = np.append(up.children[p], new_j)
-        idx._aug_extra = [None] * len(idx.levels)
+        self.index.journal.record(
+            structural=(l == 0), reason="split" if l == 0 else "split_upper")
+        apply_split(self.index, l, j, c2, a2)
 
     def _refine_around(self, l: int, seeds: List[int]) -> None:
         """Partition refinement (paper §4.2.1): one k-means round seeded by

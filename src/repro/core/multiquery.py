@@ -326,9 +326,9 @@ class PlannerCache:
 # ---------------------------------------------------------------------------
 
 def _aps_candidate_budget(index: QuakeIndex) -> int:
-    cfg = index.config
     p = index.levels[0].num_partitions
-    return min(max(int(np.ceil(cfg.f_m * p)), cfg.min_candidates), p)
+    return min(max(int(np.ceil(index.aps_f_m * p)),
+                   index.config.min_candidates), p)
 
 
 def _aps_probe_counts_loop(index: QuakeIndex, q: np.ndarray, k: int,
@@ -971,6 +971,21 @@ def _batch_rho_fn(index: QuakeIndex, q: np.ndarray):
         max_norm_sq=m2)
 
 
+def _check_fits_device(p: int, s_cap: int, d: int, largest: int) -> None:
+    """Refuse, before staging it, a dense snapshot larger than the
+    device's memory.  The block is staged in f32 whatever the storage
+    dtype."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    need = p * s_cap * d * 4
+    if limit and need > limit:
+        raise MemoryError(
+            f"dense snapshot of {p} partitions x {s_cap} slots x d={d} "
+            f"(f32) needs {need / 1e9:.2f} GB, more than the device's "
+            f"{limit / 1e9:.2f} GB; the largest partition holds {largest} "
+            f"vectors")
+
+
 class BatchedSearchExecutor:
     """Executes planned batches against a device-resident snapshot.
 
@@ -1042,6 +1057,9 @@ class BatchedSearchExecutor:
                                  # (refreshed with the snapshot)
         self.full_rebuilds = 0   # refresh telemetry (tests / bench)
         self.delta_refreshes = 0
+        self.last_scan = None    # operand shapes and arguments of the
+                                 # last round scan (f32/bf16 storage): what
+                                 # a check of the compiled program lowers
 
     def _fingerprint(self):
         return (self.index.version, self.index.num_partitions,
@@ -1067,11 +1085,14 @@ class BatchedSearchExecutor:
         maintenance epochs."""
         import math as _math
         from .distributed import IndexSnapshot  # late: avoid import cycle
+        from .maintenance import fit_to_capacity
+        fixed = fit_to_capacity(self.index, self.headroom)
         lvl0 = self.index.levels[0]
         max_sz = int(max((len(v) for v in lvl0.vectors), default=0))
         cap = max(int(_math.ceil(max_sz * max(self.headroom, 1.0))), 1)
         if self._snap is not None:
             cap = max(cap, int(self._snap.capacity))
+        cap = fixed or cap
         pad_to = self.part_bucket
         if self.part_bucket > 1:
             # partition padding is sticky too, with 25% growth slack, so
@@ -1085,6 +1106,12 @@ class BatchedSearchExecutor:
             # the absolute-target usage here is only sound while the
             # target covers the live count (ceil(p/pad_to) == 1)
             pad_to = max(pad_to, lvl0.num_partitions)
+        _check_fits_device(-(-lvl0.num_partitions // pad_to) * pad_to,
+                           IndexSnapshot.align_capacity(cap),
+                           self.index.dim, max_sz)
+        # release the old snapshot before staging the new one: at chip
+        # scale the two do not fit in device memory together
+        self._snap = self._valid = None
         snap = IndexSnapshot.from_index(self.index, capacity=cap,
                                         pad_partitions_to=pad_to)
         self._valid = snap.ids >= 0
@@ -1104,6 +1131,24 @@ class BatchedSearchExecutor:
         self._key = self._fingerprint()
         self.full_rebuilds += 1
         return self._snap
+
+    def footprint(self) -> dict:
+        """Device footprint of the cached snapshot: the dense
+        ``(P, S_cap, d)`` block next to the live vectors it holds (the
+        rest is slot and partition padding) and how it was kept fresh."""
+        snap = self._snap
+        if snap is None:
+            return {}
+        p, cap, d = snap.data.shape
+        live = int(self._sizes.sum())
+        return {"partitions": int(p), "capacity": int(cap), "dim": int(d),
+                "dtype": str(snap.data.dtype),
+                "device_bytes": int(snap.data.nbytes),
+                "live_vectors": live,
+                "live_bytes": live * int(d) * snap.data.dtype.itemsize,
+                "largest_partition": int(self._sizes.max(initial=0)),
+                "full_rebuilds": self.full_rebuilds,
+                "delta_refreshes": self.delta_refreshes}
 
     def _refresh_delta(self, delta) -> bool:
         """Patch the dirty partition rows in place of a rebuild.  Returns
@@ -1176,6 +1221,8 @@ class BatchedSearchExecutor:
         fp = self._fingerprint()
         if self._key == fp:
             return self._snap
+        from .maintenance import fit_to_capacity  # late: import cycle
+        fit_to_capacity(self.index, self.headroom)
         delta = self.index.journal.delta_since(self._key[0])
         if delta is None or not self._refresh_delta(delta):
             self.refresh()
@@ -1326,6 +1373,12 @@ class BatchedSearchExecutor:
                 q_dev, snap.data, self._valid, sel_dev, qmask_dev,
                 k_keep, metric=self.index.config.metric,
                 impl=impl or self.impl)
+            self.last_scan = {
+                "operands": [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in
+                             (q_dev, snap.data, self._valid, sel_dev,
+                              qmask_dev)],
+                "k": k_keep, "metric": self.index.config.metric,
+                "impl": ops._resolve(impl or self.impl)}
         return d, flat, st
 
     def _search_rounds(self, q: np.ndarray, k: int, target: float,

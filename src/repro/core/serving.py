@@ -56,7 +56,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from ..faults import FaultInjector
+from ..faults import FaultInjector, InjectedFault
 from ..kernels.ref import MASK_DIST
 from ..obs import Observability
 from ..sanitize import TrackedLock, note_guarded, observability_counters
@@ -816,6 +816,7 @@ class RoundScheduler:
         self.scan_backoff_s = float(scan_backoff_s)
         self.scan_backoff_max_s = float(scan_backoff_max_s)
         self._last_scan_error: Optional[BaseException] = None
+        self._real_scan_error_logged = False
         if scan_backend == "auto":
             import jax
             scan_backend = ("device" if jax.default_backend() == "tpu"
@@ -1156,7 +1157,9 @@ class RoundScheduler:
         """One round scan with capped exponential backoff.  Returns the
         scan triple, or None once ``scan_retries`` retries are exhausted
         (the caller fails the in-flight batch).  A scan exception —
-        injected or real — never propagates out of the scheduler."""
+        injected or real — never propagates out of the scheduler; the
+        first real one (a compile or device error, not an
+        ``InjectedFault``) is logged with its traceback."""
         attempt = 0
         while True:
             try:
@@ -1166,6 +1169,11 @@ class RoundScheduler:
             except Exception as e:
                 self.scan_faults += 1
                 self._last_scan_error = e
+                if not isinstance(e, InjectedFault) \
+                        and not self._real_scan_error_logged:
+                    self._real_scan_error_logged = True
+                    logger.error("round scan raised (%s backend)",
+                                 self.scan_backend, exc_info=e)
                 if attempt >= self.scan_retries:
                     return None
                 self.scan_retries_used += 1
@@ -1460,6 +1468,15 @@ class ServingRuntime:
         self._ensure_ticker()
 
     # -- lifecycle -----------------------------------------------------
+
+    def stage(self) -> None:
+        """Stage the device snapshot now, as a server does before it
+        takes traffic, rather than at the first flush: writes that arrive
+        before any query then reach it as deltas.  A no-op on the host
+        scan backend."""
+        with self._engine_lock:
+            if self.scheduler.scan_backend == "device":
+                self.executor.snapshot()
 
     def close(self) -> None:
         """Stop the deadline ticker (idempotent).  Queued / in-flight

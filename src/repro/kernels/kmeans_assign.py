@@ -22,6 +22,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import pallas_compat
 from .ref import MASK_DIST
+from .scan_topk import mxu_precision
 
 Array = jax.Array
 
@@ -40,6 +41,7 @@ def _kmeans_assign_kernel(x_ref, c_ref, aux_ref, out_a_ref, out_d_ref,
     aux = aux_ref[...]    # (1, TC): ||c||^2 + pad bias
     xc = jax.lax.dot_general(
         x, c, (((1,), (1,)), ((), ())),
+        precision=mxu_precision(x.dtype),
         preferred_element_type=jnp.float32)
     dist = aux.astype(jnp.float32) - 2.0 * xc          # (TN, TC)
 

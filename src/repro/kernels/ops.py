@@ -1,8 +1,7 @@
 """Jit'd public wrappers around the scan kernels.
 
 Dispatch policy (``impl``):
-  - "jnp":    pure-jnp oracle path (XLA fuses it well on CPU; default here
-              because this container is CPU-only).
+  - "jnp":    pure-jnp oracle path (XLA fuses it well on CPU).
   - "pallas": the Pallas kernels. On CPU they execute in interpret mode
               (correctness path); on TPU they compile via Mosaic.
   - "auto":   pallas on TPU, jnp otherwise.
@@ -330,12 +329,13 @@ def _scan_selected_q8_padded(queries, codes, scales, valid, sel, qmask,
     if centroids is not None:
         cents32 = centroids.astype(jnp.float32)
         cr = jnp.einsum("pd,psd->ps", cents32,
-                        codes.astype(jnp.float32))
+                        codes.astype(jnp.float32), precision=ref.HIGHEST)
         x2 = (jnp.sum(cents32 ** 2, axis=-1)[:, None]
               + 2.0 * scales32 * cr + scales32 ** 2 * r2)
         # exact f32 query . centroid term per selected block
-        qc_full = queries.astype(jnp.float32) @ jnp.take(
-            cents32, sel, axis=0).T                           # (B, U)
+        qc_full = jnp.matmul(queries.astype(jnp.float32),
+                             jnp.take(cents32, sel, axis=0).T,
+                             precision=ref.HIGHEST)           # (B, U)
     else:
         x2 = scales32 ** 2 * r2
         qc_full = jnp.zeros((B, U), jnp.float32)

@@ -1,21 +1,12 @@
-"""Version-portability seam for the Pallas TPU kernels.
+"""The one place the kernels name the Pallas TPU grid and compiler-params
+API (``pltpu.CompilerParams``, ``GridDimensionSemantics``,
+``PrefetchScalarGridSpec``).
 
-The Pallas TPU API surface has churned across JAX releases:
-
-  * ``pltpu.TPUCompilerParams`` (<= 0.4.x / 0.5.x) was renamed to
-    ``pltpu.CompilerParams`` (0.6+); both take the same fields.
-  * ``dimension_semantics`` entries were plain strings (``"parallel"`` /
-    ``"arbitrary"``) before the ``pltpu.GridDimensionSemantics`` enum
-    existed; newer versions accept the enum (and keep accepting strings,
-    but the enum is the documented form).
-
-Every kernel in this package dispatches through this module instead of
-touching ``pltpu`` naming directly, so a JAX upgrade (or downgrade) is a
-one-file change.  Kernels express dimension semantics with the string
-tokens ``PARALLEL`` / ``ARBITRARY`` exported here; :func:`compiler_params`
-translates them to whatever the installed JAX expects.
-
-See ``docs/compat.md`` for the repo-wide compat policy.
+Kernels express dimension semantics with ``PARALLEL`` / ``ARBITRARY`` and
+build their params and scalar-prefetch grids through the two helpers
+below, so a change of that API is a one-file edit (quakecheck QK103
+enforces the rule).  The repo runs on one JAX release; see
+``docs/compat.md``.
 """
 from __future__ import annotations
 
@@ -26,47 +17,20 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["PARALLEL", "ARBITRARY", "compiler_params",
            "prefetch_scalar_grid_spec"]
 
-# Canonical tokens used by the kernel files.  Strings on purpose: they are
-# the lowest common denominator and the enum (when present) is derived from
-# them at dispatch time.
-PARALLEL = "parallel"
-ARBITRARY = "arbitrary"
-
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-_DIM_ENUM = getattr(pltpu, "GridDimensionSemantics", None)
-
-
-def _dim_token(sem: Any) -> Any:
-    """Map a string token to the installed JAX's dimension-semantics type."""
-    if _DIM_ENUM is not None and isinstance(sem, str):
-        return getattr(_DIM_ENUM, sem.upper())
-    return sem
+PARALLEL = pltpu.GridDimensionSemantics.PARALLEL
+ARBITRARY = pltpu.GridDimensionSemantics.ARBITRARY
 
 
 def compiler_params(*, dimension_semantics: Sequence[Any], **kwargs: Any):
-    """Build TPU compiler params portably.
-
-    ``dimension_semantics`` entries may be the string tokens exported by
-    this module (or raw enum members on new JAX); extra kwargs are passed
-    through to the underlying params class.
-    """
-    sems = tuple(_dim_token(s) for s in dimension_semantics)
-    return _COMPILER_PARAMS_CLS(dimension_semantics=sems, **kwargs)
+    """Mosaic compiler params; extra kwargs pass through."""
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics), **kwargs)
 
 
 def prefetch_scalar_grid_spec(*, num_scalar_prefetch: int, grid, in_specs,
                               out_specs, scratch_shapes):
-    """Scalar-prefetch grid spec, isolated here because the class has moved
-    between releases.  Raises a clear error if the installed JAX dropped it
-    entirely (at which point this shim is the single place to update)."""
-    cls = getattr(pltpu, "PrefetchScalarGridSpec", None)
-    if cls is None:  # pragma: no cover - future-JAX escape hatch
-        raise NotImplementedError(
-            "this JAX version has no pltpu.PrefetchScalarGridSpec; update "
-            "repro.kernels.pallas_compat.prefetch_scalar_grid_spec for the "
-            "new scalar-prefetch API")
-    return cls(num_scalar_prefetch=num_scalar_prefetch, grid=grid,
-               in_specs=in_specs, out_specs=out_specs,
-               scratch_shapes=scratch_shapes)
+    """Scalar-prefetch grid spec (index maps read the prefetched operands)."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=num_scalar_prefetch, grid=grid,
+        in_specs=in_specs, out_specs=out_specs,
+        scratch_shapes=scratch_shapes)

@@ -19,6 +19,11 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
+# XLA dots on a TPU default to one bf16 pass, which the ||q||^2 + ||x||^2 -
+# 2 q.x identity cannot afford (k-means at d=768 collapsed on the chip);
+# the references and the f32 jnp paths ask for full f32 precision.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 # Large-but-finite sentinel: keeps masked lanes inert without generating NaNs
 # in downstream arithmetic (inf - inf).  Plain float so Pallas kernels can use
 # it without capturing a traced constant.
@@ -33,14 +38,14 @@ def pairwise_l2_sq(queries: Array, xs: Array) -> Array:
     """
     q2 = jnp.sum(queries * queries, axis=-1, keepdims=True)  # (Q, 1)
     x2 = jnp.sum(xs * xs, axis=-1)  # (N,)
-    qx = queries @ xs.T  # (Q, N)
+    qx = jnp.matmul(queries, xs.T, precision=HIGHEST)  # (Q, N)
     d = q2 + x2[None, :] - 2.0 * qx
     return jnp.maximum(d, 0.0)
 
 
 def pairwise_ip(queries: Array, xs: Array) -> Array:
     """Inner-product scores, (Q, d) x (N, d) -> (Q, N)."""
-    return queries @ xs.T
+    return jnp.matmul(queries, xs.T, precision=HIGHEST)
 
 
 def scan_distances(queries: Array, xs: Array, metric: str = "l2",
@@ -112,11 +117,12 @@ def scan_selected_ref(queries: Array, data: Array, aux_valid: Array,
     queries = queries.astype(jnp.float32)
     if metric == "l2":
         x2 = jnp.sum(blocks * blocks, axis=-1)      # (U, S)
-        qx = jnp.einsum("usd,bd->bus", blocks, queries)
+        qx = jnp.einsum("usd,bd->bus", blocks, queries, precision=HIGHEST)
         q2 = jnp.sum(queries * queries, axis=-1)[:, None, None]
         dist = jnp.maximum(x2[None] - 2.0 * qx + q2, 0.0)
     else:
-        dist = -jnp.einsum("usd,bd->bus", blocks, queries)
+        dist = -jnp.einsum("usd,bd->bus", blocks, queries,
+                           precision=HIGHEST)
     dist = jnp.where(valid[None], dist, MASK_DIST)
     dist = jnp.where(qmask[:, :, None], dist, MASK_DIST)
     S = data.shape[1]

@@ -21,8 +21,14 @@ Grid: ``(q_tiles, U, S/TS)`` with dimension_semantics
 (PARALLEL, ARBITRARY, ARBITRARY) — the two sequential axes walk selected
 blocks and their sub-tiles while the running top-k scratch persists.
 
+Per-slot operands (``aux``, int8 scales) travel as ``(P, 1, S)`` so a
+``(1, 1, TS)`` block meets Mosaic's (8, 128) tiling rule; per-query
+operands (qmask bias, ``qc``) travel as whole ``(TQ, U)`` rows and the
+kernel picks column ``u`` with a one-hot lane reduction.
+
 Validated in interpret mode against ``ref.scan_selected_ref`` (tests sweep
-shapes/selection patterns/metrics); Mosaic/TPU is the deployment target.
+shapes/selection patterns/metrics), compiled for a described v5e by
+``tests/test_tpu_compile.py``, and run on the chip by ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -36,14 +42,23 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import pallas_compat
 from .ref import MASK_DIST
-from .scan_topk import _is_pow2, bitonic_sort, merge_sorted_topk
+from .scan_topk import (_is_pow2, lane_width, mxu_precision,
+                        tile_topk_update)
 
 Array = jax.Array
 
 
+def _column(ref, u) -> Array:
+    """Column ``u`` of a (TQ, U) block as (TQ, 1), by a one-hot lane
+    reduction (Mosaic has no dynamic lane slice)."""
+    v = ref[...].astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+    return jnp.sum(jnp.where(lane == u, v, 0.0), axis=1, keepdims=True)
+
+
 def _scan_indexed_kernel(sel_ref, q_ref, x_ref, aux_ref, qmask_ref,
                          out_d_ref, out_i_ref, run_d, run_i, *,
-                         k_pad: int, coef: float, n_sel: int, n_sub: int,
+                         coef: float, n_sel: int, n_sub: int,
                          block_s: int, s_cap: int):
     u = pl.program_id(1)
     s = pl.program_id(2)
@@ -55,23 +70,18 @@ def _scan_indexed_kernel(sel_ref, q_ref, x_ref, aux_ref, qmask_ref,
 
     q = q_ref[...]                      # (TQ, d)
     x = x_ref[0]                        # (TS, d)
-    aux = aux_ref[0]                    # (TS,): ||x||^2 (+pad bias) or bias
-    qb = qmask_ref[...]                 # (TQ, 1): per-query selection bias
+    aux = aux_ref[0]                    # (1, TS): ||x||^2 (+pad bias) or bias
+    qb = _column(qmask_ref, u)          # (TQ, 1): per-query selection bias
     qx = jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())),
+        precision=mxu_precision(x.dtype),
         preferred_element_type=jnp.float32)      # MXU (TQ, TS)
-    dist = aux[None, :].astype(jnp.float32) + coef * qx \
-        + qb.astype(jnp.float32)
+    dist = aux.astype(jnp.float32) + coef * qx + qb
 
     part = sel_ref[u]                   # selected partition id (scalar)
     base = part * s_cap + s * block_s
     idx = base + jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
-
-    d_sorted, i_sorted = bitonic_sort(dist, idx)
-    m_d, m_i = merge_sorted_topk(run_d[...], run_i[...],
-                                 d_sorted[:, :k_pad], i_sorted[:, :k_pad])
-    run_d[...] = m_d
-    run_i[...] = m_i
+    tile_topk_update(run_d, run_i, dist, idx)
 
     @pl.when((u == n_sel - 1) & (s == n_sub - 1))
     def _write():
@@ -107,8 +117,9 @@ def scan_topk_indexed_pallas(queries: Array, data: Array, aux: Array,
     nq, ns = B // block_q, S // block_s
     coef = -2.0 if metric == "l2" else -1.0
 
+    kw = lane_width(k_pad, block_s)
     kernel = functools.partial(
-        _scan_indexed_kernel, k_pad=k_pad, coef=coef, n_sel=U, n_sub=ns,
+        _scan_indexed_kernel, coef=coef, n_sel=U, n_sub=ns,
         block_s=block_s, s_cap=S)
     grid_spec = pallas_compat.prefetch_scalar_grid_spec(
         num_scalar_prefetch=1,
@@ -117,25 +128,25 @@ def scan_topk_indexed_pallas(queries: Array, data: Array, aux: Array,
             pl.BlockSpec((block_q, d), lambda i, u, s, sel_r: (i, 0)),
             pl.BlockSpec((1, block_s, d),
                          lambda i, u, s, sel_r: (sel_r[u], s, 0)),
-            pl.BlockSpec((1, block_s),
-                         lambda i, u, s, sel_r: (sel_r[u], s)),
-            pl.BlockSpec((block_q, 1), lambda i, u, s, sel_r: (i, u)),
+            pl.BlockSpec((1, 1, block_s),
+                         lambda i, u, s, sel_r: (sel_r[u], 0, s)),
+            pl.BlockSpec((block_q, U), lambda i, u, s, sel_r: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_q, k_pad), lambda i, u, s, sel_r: (i, 0)),
-            pl.BlockSpec((block_q, k_pad), lambda i, u, s, sel_r: (i, 0)),
+            pl.BlockSpec((block_q, kw), lambda i, u, s, sel_r: (i, 0)),
+            pl.BlockSpec((block_q, kw), lambda i, u, s, sel_r: (i, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, k_pad), jnp.float32),
-            pltpu.VMEM((block_q, k_pad), jnp.int32),
+            pltpu.VMEM((block_q, kw), jnp.float32),
+            pltpu.VMEM((block_q, kw), jnp.int32),
         ],
     )
     out_d, out_i = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, k_pad), jnp.float32),
-            jax.ShapeDtypeStruct((B, k_pad), jnp.int32),
+            jax.ShapeDtypeStruct((B, kw), jnp.float32),
+            jax.ShapeDtypeStruct((B, kw), jnp.int32),
         ],
         compiler_params=pallas_compat.compiler_params(
             dimension_semantics=(pallas_compat.PARALLEL,
@@ -143,8 +154,8 @@ def scan_topk_indexed_pallas(queries: Array, data: Array, aux: Array,
                                  pallas_compat.ARBITRARY)),
         interpret=interpret,
         name="quake_scan_topk_indexed",
-    )(sel, queries, data, aux, qmask)
-    return out_d, out_i
+    )(sel, queries, data, aux.reshape(P, 1, S), qmask)
+    return out_d[:, :k_pad], out_i[:, :k_pad]
 
 
 # ---------------------------------------------------------------------------
@@ -153,9 +164,9 @@ def scan_topk_indexed_pallas(queries: Array, data: Array, aux: Array,
 
 def _scan_indexed_q8_kernel(sel_ref, q_ref, qscale_ref, x_ref, scale_ref,
                             aux_ref, qc_ref, qmask_ref, out_d_ref,
-                            out_i_ref, run_d, run_i, *, k_pad: int,
-                            coef: float, n_sel: int, n_sub: int,
-                            block_s: int, s_cap: int):
+                            out_i_ref, run_d, run_i, *, coef: float,
+                            n_sel: int, n_sub: int, block_s: int,
+                            s_cap: int):
     """Same scan, int8 codes: the MXU runs int8 x int8 -> int32 and the
     scalar product is dequantized with per-query x per-slot scales.  The
     dominant HBM stream (the vector codes) shrinks 4x vs f32.
@@ -176,27 +187,22 @@ def _scan_indexed_q8_kernel(sel_ref, q_ref, qscale_ref, x_ref, scale_ref,
 
     q = q_ref[...]                      # (TQ, d) int8 codes
     x = x_ref[0]                        # (TS, d) int8 codes
-    aux = aux_ref[0]                    # (TS,): dequantized ||x||^2 + bias
-    qb = qmask_ref[...]                 # (TQ, 1)
-    qc = qc_ref[...]                    # (TQ, 1) f32 q . c_{sel[u]}
+    aux = aux_ref[0]                    # (1, TS): dequantized ||x||^2 + bias
+    qb = _column(qmask_ref, u)          # (TQ, 1)
+    qc = _column(qc_ref, u)             # (TQ, 1) f32 q . c_{sel[u]}
     qs = qscale_ref[...]                # (TQ, 1) per-query dequant scale
-    xs = scale_ref[0]                   # (TS,)  per-slot dequant scale
+    xs = scale_ref[0]                   # (1, TS) per-slot dequant scale
     qx_i = jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32)        # MXU int8 path
-    qx = qc.astype(jnp.float32) + qx_i.astype(jnp.float32) \
-        * qs.astype(jnp.float32) * xs[None, :].astype(jnp.float32)
-    dist = aux[None, :].astype(jnp.float32) + coef * qx \
-        + qb.astype(jnp.float32)
+    qx = qc + qx_i.astype(jnp.float32) * qs.astype(jnp.float32) \
+        * xs.astype(jnp.float32)
+    dist = aux.astype(jnp.float32) + coef * qx + qb
 
     part = sel_ref[u]
     base = part * s_cap + s * block_s
     idx = base + jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
-    d_sorted, i_sorted = bitonic_sort(dist, idx)
-    m_d, m_i = merge_sorted_topk(run_d[...], run_i[...],
-                                 d_sorted[:, :k_pad], i_sorted[:, :k_pad])
-    run_d[...] = m_d
-    run_i[...] = m_i
+    tile_topk_update(run_d, run_i, dist, idx)
 
     @pl.when((u == n_sel - 1) & (s == n_sub - 1))
     def _write():
@@ -229,8 +235,9 @@ def scan_topk_indexed_q8_pallas(q_codes: Array, q_scales: Array,
     nq, ns = B // block_q, S // block_s
     coef = -2.0 if metric == "l2" else -1.0
 
+    kw = lane_width(k_pad, block_s)
     kernel = functools.partial(
-        _scan_indexed_q8_kernel, k_pad=k_pad, coef=coef, n_sel=U, n_sub=ns,
+        _scan_indexed_q8_kernel, coef=coef, n_sel=U, n_sub=ns,
         block_s=block_s, s_cap=S)
     grid_spec = pallas_compat.prefetch_scalar_grid_spec(
         num_scalar_prefetch=1,
@@ -240,28 +247,28 @@ def scan_topk_indexed_q8_pallas(q_codes: Array, q_scales: Array,
             pl.BlockSpec((block_q, 1), lambda i, u, s, sel_r: (i, 0)),
             pl.BlockSpec((1, block_s, d),
                          lambda i, u, s, sel_r: (sel_r[u], s, 0)),
-            pl.BlockSpec((1, block_s),
-                         lambda i, u, s, sel_r: (sel_r[u], s)),
-            pl.BlockSpec((1, block_s),
-                         lambda i, u, s, sel_r: (sel_r[u], s)),
-            pl.BlockSpec((block_q, 1), lambda i, u, s, sel_r: (i, u)),
-            pl.BlockSpec((block_q, 1), lambda i, u, s, sel_r: (i, u)),
+            pl.BlockSpec((1, 1, block_s),
+                         lambda i, u, s, sel_r: (sel_r[u], 0, s)),
+            pl.BlockSpec((1, 1, block_s),
+                         lambda i, u, s, sel_r: (sel_r[u], 0, s)),
+            pl.BlockSpec((block_q, U), lambda i, u, s, sel_r: (i, 0)),
+            pl.BlockSpec((block_q, U), lambda i, u, s, sel_r: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_q, k_pad), lambda i, u, s, sel_r: (i, 0)),
-            pl.BlockSpec((block_q, k_pad), lambda i, u, s, sel_r: (i, 0)),
+            pl.BlockSpec((block_q, kw), lambda i, u, s, sel_r: (i, 0)),
+            pl.BlockSpec((block_q, kw), lambda i, u, s, sel_r: (i, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, k_pad), jnp.float32),
-            pltpu.VMEM((block_q, k_pad), jnp.int32),
+            pltpu.VMEM((block_q, kw), jnp.float32),
+            pltpu.VMEM((block_q, kw), jnp.int32),
         ],
     )
     out_d, out_i = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, k_pad), jnp.float32),
-            jax.ShapeDtypeStruct((B, k_pad), jnp.int32),
+            jax.ShapeDtypeStruct((B, kw), jnp.float32),
+            jax.ShapeDtypeStruct((B, kw), jnp.int32),
         ],
         compiler_params=pallas_compat.compiler_params(
             dimension_semantics=(pallas_compat.PARALLEL,
@@ -269,8 +276,9 @@ def scan_topk_indexed_q8_pallas(q_codes: Array, q_scales: Array,
                                  pallas_compat.ARBITRARY)),
         interpret=interpret,
         name="quake_scan_topk_indexed_q8",
-    )(sel, q_codes, q_scales, data_codes, data_scales, aux, qc, qmask)
-    return out_d, out_i
+    )(sel, q_codes, q_scales, data_codes, data_scales.reshape(P, 1, S),
+      aux.reshape(P, 1, S), qc, qmask)
+    return out_d[:, :k_pad], out_i[:, :k_pad]
 
 
 def quantize_int8(x: Array, axis: int = -1) -> Tuple[Array, Array]:

@@ -2,6 +2,8 @@
 # anything importing jax) is imported — jax locks the device count on
 # first backend initialization.
 import os  # noqa: E402
+# a CPU study on 512 virtual devices: it must never take the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
 
@@ -32,11 +34,9 @@ from typing import Dict, Optional
 
 import jax
 
-from ..compat import cost_analysis_dict
 from ..configs import REGISTRY, get_arch
-from ..roofline.analysis import analyze_compiled, HW_V5E
+from ..roofline.analysis import DRYRUN_TARGET_KIND, analyze_compiled
 from .mesh import describe, make_production_mesh
-
 
 def run_cell(arch: str, shape: str, mesh, *, verbose: bool = True) -> Dict:
     spec = get_arch(arch)
@@ -49,8 +49,9 @@ def run_cell(arch: str, shape: str, mesh, *, verbose: bool = True) -> Dict:
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
-    result = analyze_compiled(compiled, mesh, arch=arch, shape=shape)
+    cost = compiled.cost_analysis()
+    result = analyze_compiled(compiled, mesh, device_kind=DRYRUN_TARGET_KIND,
+                              arch=arch, shape=shape)
     result.update({
         "lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1),
         "description": lowering.description,
@@ -105,6 +106,8 @@ def main() -> None:
         with open(args.out) as f:
             results = json.load(f)
 
+    print(f"roofline terms below are compile-time estimates from a CPU "
+          f"compile against {DRYRUN_TARGET_KIND} peaks; not measured")
     failures = []
     for mesh_name, mesh in meshes:
         print(f"=== mesh {mesh_name}: {describe(mesh)} ===")

@@ -17,6 +17,7 @@ against).
 from __future__ import annotations
 
 import argparse
+import gc
 import json
 import time
 
@@ -24,10 +25,13 @@ import numpy as np
 
 from ..core import (LatencyModel, Maintainer, QuakeConfig, QuakeIndex,
                     ServingConfig, ServingRuntime)
+from ..core.serving import STATUS_FAILED
 from ..data import wikipedia
 from ..data.workload import IncrementalGroundTruth
 from ..faults import FaultInjector
+from .. import sanitize
 from ..obs import summarize, to_prometheus
+from .compile_cache import configure_compile_cache
 
 
 def parse_fault_spec(spec: str, seed: int = 0) -> FaultInjector:
@@ -84,6 +88,10 @@ def _warm_runtime(index, wl, scfg: ServingConfig) -> None:
         shadow.drain()
     finally:
         shadow.close()
+    # free the shadow's device snapshot before the timed runtime stages its
+    # own: at chip scale two of them do not fit in HBM
+    del shadow
+    gc.collect()
 
 
 def replay_runtime(wl, cfg: QuakeConfig, scfg: ServingConfig,
@@ -92,26 +100,40 @@ def replay_runtime(wl, cfg: QuakeConfig, scfg: ServingConfig,
                    faults: FaultInjector | None = None,
                    metrics_out: str | None = None,
                    trace_out: str | None = None,
-                   metrics_every: int = 16) -> dict:
+                   metrics_every: int = 16,
+                   after_replay=None) -> dict:
     """Replay a workload through the serving runtime; returns the summary
     dict ``bench_serving`` consumes (wall-clock excludes ground truth;
     ``warm=True`` pre-compiles the jitted shapes so the measurement is
     steady-state serving, not XLA compile time; ``settle=True`` runs one
     maintenance pass right after the build, before serving starts —
     fresh k-means builds leave oversized partitions that the paper's
-    system would split immediately)."""
+    system would split immediately).  ``after_replay(runtime)``, when
+    given, runs once after the last operation, before the runtime closes;
+    what it returns is kept under ``"after_replay"``."""
     k = scfg.k
     t0 = time.time()
     index = QuakeIndex.build(wl.initial_vectors, wl.initial_ids, config=cfg)
     maintainer = Maintainer(index, LatencyModel(dim=index.dim))
+    build_s = time.time() - t0
+    t0 = time.time()
     if settle:
         maintainer.run()
-    if warm:
-        _warm_runtime(index, wl, scfg)
-    rt = ServingRuntime(index, scfg, maintainer=maintainer, faults=faults)
+    settle_s = time.time() - t0
     if verbose:
         print(f"built: {index.num_vectors} vectors, "
-              f"{index.num_partitions} partitions ({time.time()-t0:.1f}s)")
+              f"{index.num_partitions} partitions (largest "
+              f"{int(index.levels[0].sizes().max())}; {build_s:.1f}s build, "
+              f"{settle_s:.1f}s maintenance)")
+    t0 = time.time()
+    if warm:
+        _warm_runtime(index, wl, scfg)
+    warm_s = time.time() - t0
+    compiles = sanitize.CompileEvents()   # compiles inside the window
+    rt = ServingRuntime(index, scfg, maintainer=maintainer, faults=faults)
+    t0 = time.time()
+    rt.stage()
+    stage_s = time.time() - t0
 
     gt_inc = IncrementalGroundTruth(wl.dataset, wl.initial_ids)
     recalls, latencies = [], []
@@ -173,9 +195,19 @@ def replay_runtime(wl, cfg: QuakeConfig, scfg: ServingConfig,
     if rt.obs is not None:
         cal = {"latency_rel_err": rt.obs.calibration.latency_error(),
                "recall_abs_err": rt.obs.calibration.recall_error()}
+    footprint = rt.executor.footprint()
+    after = after_replay(rt) if after_replay is not None else None
     rt.close()                    # join the deadline ticker, if configured
     lat = summarize(latencies)    # the repo-wide shared percentile path
     out = {"mode": "runtime", "serve_s": round(serve_s, 3),
+           "build_s": round(build_s, 3), "settle_s": round(settle_s, 3),
+           "warm_s": round(warm_s, 3), "stage_s": round(stage_s, 3),
+           "compiles_in_window": compiles.new(),
+           "resident_vectors": index.num_vectors,
+           "snapshot": footprint,
+           "last_scan": rt.executor.last_scan,
+           "aps_calibration": index.aps_calibration,
+           "scan_faults": st["scan_faults"],
            "n_queries": n_queries,
            "qps": round(n_queries / max(serve_s, 1e-9), 1),
            "mean_recall": round(float(np.mean(recalls)), 4)
@@ -192,6 +224,8 @@ def replay_runtime(wl, cfg: QuakeConfig, scfg: ServingConfig,
            "queries_shed": st["queries_shed"]}
     if cal is not None:
         out["calibration"] = cal
+    if after is not None:
+        out["after_replay"] = after
     if faults is not None or st["maintenance_failures"] or \
             st["cache_disabled"] or st["ticker_errors"]:
         out["failure_telemetry"] = {
@@ -345,6 +379,7 @@ def main(argv=None) -> None:
                     help="disable the metrics registry / tracer / "
                          "calibration tracker entirely")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     if args.recover:
         if args.wal_dir is None:
@@ -390,9 +425,16 @@ def main(argv=None) -> None:
         scfg.maint_max_ops = None
     faults = (parse_fault_spec(args.faults, seed=args.fault_seed)
               if args.faults else None)
-    replay_runtime(wl, cfg, scfg, faults=faults,
-                   metrics_out=args.metrics_out, trace_out=args.trace_out,
-                   metrics_every=args.metrics_every)
+    out = replay_runtime(wl, cfg, scfg, faults=faults,
+                         metrics_out=args.metrics_out,
+                         trace_out=args.trace_out,
+                         metrics_every=args.metrics_every)
+    failed = out["status_counts"].get(STATUS_FAILED, 0)
+    if failed and faults is None:
+        # no fault was injected, so a FAILED query is a real scan error
+        # (logged with its traceback by the scheduler)
+        raise SystemExit(f"{failed} queries FAILED with no injected "
+                         f"faults")
 
 
 if __name__ == "__main__":

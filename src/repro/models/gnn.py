@@ -7,8 +7,8 @@ edge-softmax -> SpMM, expressed as segment ops).
 
 Distribution: **edge-parallel** — the edge list is sharded across the data
 axes; every segment reduction takes a local partial then a ``psum`` over the
-axis (pass ``axis=("pod","data")`` inside ``repro.compat.shard_map``, the
-version-portable alias — see docs/compat.md).  Node features are
+axis (pass ``axis=("pod","data")`` inside ``jax.shard_map``).  Node
+features are
 replicated (fine for Cora/molecule; ogb_products keeps features resident and
 trades the replicated gather — see DESIGN.md §6 / the §Perf log).
 """

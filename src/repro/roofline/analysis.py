@@ -10,9 +10,9 @@ parse the optimized HLO: every all-gather / all-reduce / reduce-scatter /
 all-to-all / collective-permute, with ring-algorithm wire-byte formulas and
 group sizes from ``replica_groups``.
 
-Hardware model (TPU v5e per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-50 GB/s/link ICI (single-link conservative basis; the task's
-``collective_bytes / (chips x link_bw)`` convention).
+Peaks come from :data:`PEAKS`, keyed by ``device_kind``.  A run on the
+CPU compiles for a named target kind and its terms are compile-time
+estimates, never measurements.
 """
 from __future__ import annotations
 
@@ -20,9 +20,35 @@ import re
 from dataclasses import dataclass
 from typing import Dict, Optional
 
+import jax
 import numpy as np
 
-HW_V5E = {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9}
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM at 819 GB/s, and
+# 1,600 Gbit/s of chip-to-chip interconnect over 4 links, i.e. 50 GB/s per
+# link (the collective term charges one link: a conservative basis).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"peak_flops": 197e12, "peak_int8_ops": 393e12,
+                    "hbm_bw": 819e9, "hbm_bytes": 16e9, "ici_bw": 50e9},
+}
+
+
+# The chip a CPU dry run compiles for (its terms are estimates from that
+# compile, never measurements).
+DRYRUN_TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of one chip of ``device_kind``; an unknown kind is an error,
+    never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; add them to "
+                         f"repro.roofline.analysis.PEAKS with their "
+                         f"source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s2": 1, "s4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -102,15 +128,18 @@ def parse_collectives(hlo_text: str) -> Dict:
             "by_kind": {k: v for k, v in per_kind.items() if v}}
 
 
-def analyze_compiled(compiled, mesh, *, arch: str = "", shape: str = "",
-                     hw: Dict = HW_V5E) -> Dict:
-    """Trip-count-aware roofline terms for one compiled cell.
+def analyze_compiled(compiled, mesh, *, device_kind: str, arch: str = "",
+                     shape: str = "") -> Dict:
+    """Trip-count-aware roofline terms for one compiled cell, against the
+    peaks of ``device_kind`` (the chip the cell targets; name it even when
+    the compile ran on the CPU).
 
     flops / bytes / wire-bytes come from ``hlo_cost.analyze`` (XLA's
     ``cost_analysis()`` counts while bodies once — worthless for
     scan-over-layers programs); per-device residency from
     ``memory_analysis()``."""
     from . import hlo_cost
+    hw = peaks(device_kind)
     c = hlo_cost.analyze(compiled.as_text())
     flops = c.flops
     bytes_acc = c.bytes_accessed
@@ -129,6 +158,10 @@ def analyze_compiled(compiled, mesh, *, arch: str = "", shape: str = "",
     useful = (mf / n_dev / max(flops, 1.0)) if mf else None
     return {
         "arch": arch, "shape": shape, "devices": n_dev,
+        "target_device_kind": device_kind,
+        "source": (f"compile-time estimate from a "
+                   f"{jax.default_backend()} compile against "
+                   f"{device_kind} peaks; not measured"),
         "flops_per_device_tf": flops / 1e12,
         "hlo_bytes_per_device_gb": bytes_acc / 1e9,
         "bytes_per_device_gb": per_dev_bytes / 1e9,

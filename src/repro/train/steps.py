@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 from . import optimizer as opt
 
 Array = jax.Array

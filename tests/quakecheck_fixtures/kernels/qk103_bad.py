@@ -12,7 +12,7 @@ def _scale_kernel(x_ref, o_ref):
 
 
 def launch_scale(x):
-    params = pltpu.TPUCompilerParams()     # QK103: bypass pallas_compat
+    params = pltpu.CompilerParams()        # QK103: bypass pallas_compat
     return pl.pallas_call(                 # QK103: no divisibility guard
         _scale_kernel, out_shape=x, compiler_params=params)(x)
 

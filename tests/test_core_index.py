@@ -100,3 +100,38 @@ def test_recall_estimate_tracks_true_recall(clustered):
         est_r.append(r.recall_estimate)
     assert np.mean(true_r) >= 0.85
     assert abs(np.mean(est_r) - np.mean(true_r)) < 0.12
+
+
+def test_calibrate_aps_keeps_model_that_meets_target(clustered):
+    idx = QuakeIndex.build(clustered.vectors, num_partitions=32,
+                           config=QuakeConfig(recall_target=0.9))
+    cal = idx.aps_calibration
+    assert cal["met"] and len(cal["tried"]) == 1
+    assert idx.geometry_dim == idx.model_dim == clustered.dim
+    assert idx.aps_f_m == idx.config.f_m
+
+
+def test_calibrate_aps_fits_model_where_it_stops_early():
+    # topics of isotropic noise at d=768: a query's neighbours spread over
+    # many partitions and the unfitted cap model stops early
+    from repro.data import wikipedia
+    from repro.data.workload import IncrementalGroundTruth
+    wl = wikipedia.wikipedia_workload(n_total=20_000, dim=768, months=1,
+                                      queries_per_month=64, seed=0)
+    q = [op.queries for op in wl.operations if op.kind == "query"][0]
+    truth = IncrementalGroundTruth(wl.dataset, wl.initial_ids).topk(q, 10)
+    idx = QuakeIndex.build(wl.initial_vectors, wl.initial_ids,
+                           config=QuakeConfig(metric="ip"))
+    cal = idx.aps_calibration
+    assert cal["tried"][0][2] < cal["target"]      # the unfitted model
+    assert cal["met"] and cal["recall"] >= cal["target"]
+    assert idx.geometry_dim < idx.model_dim
+
+    def recall():
+        res = [idx.search(x, 10, record_stats=False) for x in q]
+        return np.mean([len(set(r.ids.tolist()) & set(t.tolist())) / 10
+                        for r, t in zip(res, truth)])
+
+    fitted = recall()
+    idx.set_aps_model(idx.config.f_m, idx.model_dim)
+    assert fitted >= 0.9 > recall()

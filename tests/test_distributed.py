@@ -15,8 +15,8 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-from repro.core import EngineConfig, IndexSnapshot, QuakeIndex, \
-    ShardedQuakeEngine
+from repro.core import (EngineConfig, IndexSnapshot, QuakeConfig,
+                        QuakeIndex, ShardedQuakeEngine)
 from repro.data import datasets
 
 
@@ -138,6 +138,25 @@ def test_engine_search_batch_storage_dtypes(snap_and_data, dtype):
     rec = np.mean([len(set(r.ids[i].tolist()) & set(gt[i].tolist())) / 10
                    for i in range(8)])
     assert rec >= 0.8, rec
+
+
+def test_engine_fixed_capacity_splits_to_fit(snap_and_data):
+    """A fixed slot capacity holds for the sharded snapshot too: the
+    partitions larger than it are split, and every vector stays
+    findable by a scan of every partition."""
+    _, ds = snap_and_data
+    idx = QuakeIndex.build(ds.vectors, num_partitions=8, kmeans_iters=4,
+                           config=QuakeConfig(snapshot_capacity=128))
+    p0 = idx.num_partitions
+    eng = ShardedQuakeEngine(_mesh111(), EngineConfig(
+        k=1, nprobe=4 * p0, part_axes=("pod", "data")))
+    ss = eng.refresh_snapshot(idx)
+    assert ss.capacity == 128 and idx.num_partitions > p0
+    assert idx.levels[0].sizes().max() <= 128
+    idx.check_invariants()
+    q = jnp.asarray(ds.vectors[:16])
+    d, i = eng.search_fixed(q, ss)
+    assert np.array_equal(np.asarray(i)[:, 0], np.arange(16))
 
 
 def test_engine_journal_refresh_patches_sharded_snapshot(snap_and_data):

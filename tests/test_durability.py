@@ -342,6 +342,12 @@ def test_save_load_round_trip(tmp_path, base, ops):
     loaded = QuakeIndex.load(root)
     assert index_state_fingerprint(loaded) == index_state_fingerprint(idx)
     loaded.check_invariants()
+    # APS's fitted model is index state too
+    idx.set_aps_model(0.2, 16)
+    idx.save(root)
+    loaded = QuakeIndex.load(root)
+    assert (loaded.aps_f_m, loaded.geometry_dim) == (0.2, 16)
+    np.testing.assert_array_equal(loaded._beta_table, idx._beta_table)
     # saving again bumps the generation; load picks the newest
     apply_op(idx, ops[6])
     idx.save(root)

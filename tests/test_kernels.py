@@ -90,15 +90,73 @@ def test_bitonic_sort_sorts():
 
 
 def test_merge_sorted_topk():
+    """``run`` ascending, ``new`` descending (the kernel's merge form)."""
     rng = np.random.default_rng(3)
     a = np.sort(rng.normal(size=(2, 16)), 1).astype(np.float32)
-    b = np.sort(rng.normal(size=(2, 16)), 1).astype(np.float32)
+    b = np.sort(rng.normal(size=(2, 16)), 1).astype(np.float32)[:, ::-1]
     ia = np.arange(16, dtype=np.int32)[None].repeat(2, 0)
     ib = (np.arange(16, dtype=np.int32) + 100)[None].repeat(2, 0)
     md, mi = jax.jit(merge_sorted_topk)(jnp.asarray(a), jnp.asarray(ia),
                                         jnp.asarray(b), jnp.asarray(ib))
     expect = np.sort(np.concatenate([a, b], 1), 1)[:, :16]
     np.testing.assert_allclose(np.asarray(md), expect, rtol=1e-6)
+    # payload travels with its key
+    lookup = [dict(zip(np.r_[ia[r], ib[r]].tolist(), np.r_[a[r], b[r]]))
+              for r in range(2)]
+    for r in range(2):
+        np.testing.assert_array_equal(
+            [lookup[r][x] for x in np.asarray(mi)[r].tolist()],
+            np.asarray(md)[r])
+
+
+def _lex_topk(d: np.ndarray, i: np.ndarray, k: int):
+    """Reference: the k smallest (distance, index) keys per row, ascending."""
+    order = np.lexsort((i, d), axis=1)[:, :k]
+    return (np.take_along_axis(d, order, 1),
+            np.take_along_axis(i, order, 1))
+
+
+@pytest.mark.parametrize("width", [8, 128, 512])
+@pytest.mark.parametrize("descending", [False, True])
+@pytest.mark.parametrize("ties", [False, True])
+def test_bitonic_sort_lexicographic(width, descending, ties):
+    """The network sorts (distance, index) keys, so ties resolve to the
+    smaller index in both directions."""
+    rng = np.random.default_rng(width + 2 * descending + ties)
+    d = rng.normal(size=(8, width)).astype(np.float32)
+    if ties:
+        d = np.round(d * 2) / 2           # few distinct values
+    i = rng.permutation(8 * width).reshape(8, width).astype(np.int32)
+    ds, is_ = jax.jit(bitonic_sort, static_argnums=2)(
+        jnp.asarray(d), jnp.asarray(i), descending)
+    ed, ei = _lex_topk(d, i, width)
+    if descending:
+        ed, ei = ed[:, ::-1], ei[:, ::-1]
+    np.testing.assert_array_equal(np.asarray(ds), ed)
+    np.testing.assert_array_equal(np.asarray(is_), ei)
+
+
+@pytest.mark.parametrize("kw,ties", [(8, False), (16, True), (128, False),
+                                     (128, True)])
+def test_merge_sorted_topk_descending_new(kw, ties):
+    """Merge of an ascending running list with a descending tile tail keeps
+    the kw smallest keys of both, in ascending order, with index ties
+    broken low-first."""
+    rng = np.random.default_rng(kw + ties)
+    scale = 2 if ties else 1000
+    a = np.round(rng.normal(size=(4, kw)) * scale).astype(np.float32)
+    b = np.round(rng.normal(size=(4, kw)) * scale).astype(np.float32)
+    ia = rng.permutation(4 * kw).reshape(4, kw).astype(np.int32)
+    ib = (rng.permutation(4 * kw) + 4 * kw).reshape(4, kw).astype(np.int32)
+    a, ia = _lex_topk(a, ia, kw)
+    b, ib = _lex_topk(b, ib, kw)
+    md, mi = jax.jit(merge_sorted_topk)(
+        jnp.asarray(a), jnp.asarray(ia),
+        jnp.asarray(b[:, ::-1]), jnp.asarray(ib[:, ::-1]))
+    ed, ei = _lex_topk(np.concatenate([a, b], 1),
+                       np.concatenate([ia, ib], 1), kw)
+    np.testing.assert_array_equal(np.asarray(md), ed)
+    np.testing.assert_array_equal(np.asarray(mi), ei)
 
 
 # ---------------------------------------------------------------------------

@@ -162,3 +162,34 @@ def test_latency_model_fit_and_profile():
     np.testing.assert_allclose(fit(sizes), lam0(sizes), rtol=1e-6)
     prof = cm.profile(dim=16, sizes=(64, 256, 1024), repeats=2)
     assert (prof(np.array([10, 100, 1000])) > 0).all()
+
+
+@pytest.mark.parametrize("k", [2, 7, 16, 23])
+def test_refine_padded_centroids_match_unpadded(k):
+    """Refinement pads its group's centroids to a multiple of 16 (masked)
+    so one compiled Lloyd step serves every group size; the padding rows
+    never take a point, so the result equals the unpadded step."""
+    import jax.numpy as jnp
+    from repro.core import kmeans
+    rng = np.random.default_rng(k)
+    cents = rng.normal(size=(k, 12)).astype(np.float32) * 4
+    parts = []
+    for j in range(k):
+        n = int(rng.integers(0, 40))      # empty groups included
+        parts.append((cents[j] + rng.normal(size=(n, 12)).astype(np.float32),
+                      np.arange(n, dtype=np.int64) + 1000 * j))
+    got_c, got_parts = kmeans.refine(parts, cents, iters=2)
+    xs = np.concatenate([p[0] for p in parts])
+    npad = kmeans._next_pow2(max(len(xs), 8))
+    xp = np.zeros((npad, 12), np.float32)
+    xp[:len(xs)] = xs
+    c, a, _ = kmeans._lloyd(jnp.asarray(xp),
+                            jnp.asarray(np.arange(npad) < len(xs)),
+                            jnp.asarray(cents), jnp.ones(k, bool), k, 2)
+    a = np.asarray(a)[:len(xs)]
+    assert len(got_c) == len(got_parts) == k
+    for j in range(k):
+        np.testing.assert_array_equal(got_parts[j][0], xs[a == j])
+        if (a == j).any():
+            np.testing.assert_allclose(got_c[j], np.asarray(c)[j],
+                                       rtol=1e-5, atol=1e-5)

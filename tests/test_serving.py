@@ -93,6 +93,27 @@ def test_host_and_device_backends_agree(ds):
         assert set(rh.ids.tolist()) == set(rd.ids.tolist())
 
 
+@pytest.mark.parametrize("backend", ["host", "device"])
+def test_stage_then_write_reaches_snapshot_as_delta(ds, backend):
+    idx = build(ds)
+    rt = ServingRuntime(idx, ServingConfig(
+        k=10, scan_backend=backend, maint_min_ops=10 ** 9))
+    rt.stage()
+    # a write confined to one partition: a delta, not a rebuild
+    rt.submit_insert(np.repeat(ds.vectors[:1], 5, axis=0) + 0.01,
+                     np.arange(90_000, 90_005))
+    q = datasets.queries_near(ds, 8, seed=4)
+    qids = rt.submit_batch(q)
+    rt.drain()
+    ex = rt.executor
+    if backend == "device":
+        assert ex.full_rebuilds == 1 and ex.delta_refreshes == 1
+    else:
+        assert ex._snap is None
+    assert all(r.status == "OK" for r in _result_rows(rt, qids))
+    rt.close()
+
+
 # ---------------------------------------------------------------------------
 # Riding-footprint invariant
 # ---------------------------------------------------------------------------
@@ -420,3 +441,31 @@ def test_incremental_ground_truth_matches_recompute(ds):
     resident -= set(range(0, 500))
     np.testing.assert_array_equal(gt.topk(q, 5), brute(resident))
     assert len(gt.resident_ids) == len(resident)
+
+
+@pytest.mark.parametrize("faults", [None, "scan=1.0"],
+                         ids=["real-failure", "injected"])
+def test_serve_main_exits_nonzero_on_failed_queries(monkeypatch, faults):
+    """``launch/serve.py`` exits non-zero when queries FAILED and no fault
+    was injected: a failing device path must not look like a pass."""
+    from repro.launch import serve
+    from repro.core import serving as serving_mod
+
+    def broken(self, *a, **kw):
+        raise RuntimeError("scan backend unavailable")
+    # tests keep JAX's persistent compilation cache off
+    monkeypatch.setattr(serve, "configure_compile_cache", lambda: None)
+    if faults is None:
+        monkeypatch.setattr(serving_mod.RoundScheduler, "_scan_once",
+                            broken)
+    argv = ["--n", "1500", "--dim", "8", "--months", "1",
+            "--queries-per-month", "16", "--cache-entries", "0",
+            "--no-maintenance", "--no-metrics"]
+    if faults is not None:
+        argv += ["--faults", faults]
+    if faults is None:
+        with pytest.raises(SystemExit) as e:
+            serve.main(argv)
+        assert "FAILED" in str(e.value.code)
+    else:
+        serve.main(argv)      # injected faults: failures are expected

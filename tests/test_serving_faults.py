@@ -488,3 +488,36 @@ def test_close_clean_ticker_not_wedged(ds):
     rt.close()
     assert rt.stats()["ticker_wedged"] is False
     assert rt._ticker_thread is None
+
+
+@pytest.mark.parametrize("real", [True, False], ids=["real", "injected"])
+def test_first_real_scan_error_logged_with_traceback(ds, caplog, real):
+    """A real scan error (a compile or device fault) is logged once with
+    its traceback; injected faults are counted but not logged."""
+    fi = FaultInjector(seed=1, rates={"scan": 0.0 if real else 1.0},
+                       sleep_fn=lambda s: None)
+    cfg = ServingConfig(k=10, flush_size=4, scan_backend="host",
+                        ticker=False, scan_retries=1,
+                        maint_min_ops=10 ** 9)
+    qs = datasets.queries_near(ds, 8, seed=7).astype(np.float32)
+    with ServingRuntime(build(ds), cfg, faults=fi) as rt:
+        if real:
+            def broken(*a, **kw):
+                raise ValueError("Mosaic refused the kernel")
+            rt.scheduler._scan_once = broken
+        with caplog.at_level("ERROR", logger="repro.serving"):
+            for q in qs:
+                rt.submit_query(q)
+            rt.drain()
+        st = _terminal_invariant(rt)
+        assert st["status_counts"][STATUS_FAILED] == len(qs)
+        assert st["scan_faults"] == 4          # 2 batches x (1 + 1 retry)
+    logged = [r for r in caplog.records if r.name == "repro.serving"
+              and r.levelname == "ERROR"]
+    if real:
+        assert len(logged) == 1
+        assert logged[0].exc_info is not None
+        assert "Mosaic refused the kernel" in caplog.text
+        assert "Traceback" in caplog.text
+    else:
+        assert logged == []

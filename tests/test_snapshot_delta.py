@@ -167,6 +167,34 @@ def test_structural_change_falls_back_to_rebuild():
     assert ex.full_rebuilds == 2 and ex.delta_refreshes == 0
 
 
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+def test_split_to_fit_keeps_capacity_and_patches(metric):
+    ds = datasets.clustered(2000, 8, n_clusters=8, seed=8)
+    idx = QuakeIndex.build(ds.vectors, num_partitions=8, kmeans_iters=2,
+                           config=QuakeConfig(metric=metric,
+                                              snapshot_capacity=512))
+    q = np.asarray(datasets.queries_near(ds, 4, seed=9), np.float32)
+    ex = BatchedSearchExecutor(idx, impl="jnp", part_bucket=32)
+    ex.snapshot()
+    cap, p0 = ex._snap.capacity, idx.num_partitions
+    assert cap == 512
+    # a burst of a new topic, away from every partition: it lands in the
+    # nearest few and grows them past the snapshot's slots
+    rng = np.random.default_rng(0)
+    far = np.abs(ds.vectors).max(0) * 3.0
+    xb = (far + rng.normal(scale=0.1, size=(2 * cap, idx.dim))
+          ).astype(np.float32)
+    idx.insert(xb, np.arange(200_000, 200_000 + len(xb)))
+    r = ex.search(q, 5, nprobe=idx.num_partitions)
+    assert ex.full_rebuilds == 1 and ex.delta_refreshes == 1
+    assert ex._snap.capacity == cap and idx.num_partitions > p0
+    assert idx.levels[0].sizes().max() <= cap / idx.config.snapshot_headroom
+    idx.check_invariants()
+    gt_ids, gt_d = _brute_force(idx, q, 5)
+    np.testing.assert_allclose(np.sort(r.dists, 1), np.sort(gt_d, 1),
+                               rtol=1e-3, atol=1e-3)
+
+
 def test_capacity_overflow_falls_back_to_rebuild():
     ds = datasets.clustered(2000, 8, n_clusters=8, seed=8)
     idx = QuakeIndex.build(ds.vectors, num_partitions=8, kmeans_iters=2)

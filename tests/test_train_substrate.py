@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.core import QuakeIndex
 from repro.core.multiquery import batch_search, per_query_search
 from repro.data import datasets, pipelines, wikipedia, workload
@@ -159,7 +158,7 @@ def test_hlo_cost_trip_counts():
     cf = jax.jit(flat).lower(a, a).compile()
     mine_s = hlo_cost.analyze(cs.as_text())
     mine_f = hlo_cost.analyze(cf.as_text())
-    xla_f = compat.cost_analysis_dict(cf)["flops"]
+    xla_f = cf.cost_analysis()["flops"]
     assert mine_f.flops == pytest.approx(xla_f, rel=0.01)
     assert mine_s.flops == pytest.approx(mine_f.flops, rel=0.02)
 

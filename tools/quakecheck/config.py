@@ -61,11 +61,11 @@ DATA_DEPENDENT_REDUCERS = {"max", "min", "sum", "argmax", "argmin",
 # --------------------------------------------------------------------------
 # QK103 — Pallas kernel contract
 # --------------------------------------------------------------------------
-# pltpu names that have churned across JAX releases; kernels must reach
-# them through kernels/pallas_compat.py, never directly.
+# The Mosaic grid / compiler-params API; kernels reach it through
+# kernels/pallas_compat.py, never directly, so a change of that API is a
+# one-file edit.
 PLTPU_COMPAT_ONLY = {
-    "TPUCompilerParams", "CompilerParams", "PrefetchScalarGridSpec",
-    "GridDimensionSemantics",
+    "CompilerParams", "PrefetchScalarGridSpec", "GridDimensionSemantics",
 }
 # The one file allowed to touch them.
 PALLAS_COMPAT_FILE = "pallas_compat.py"

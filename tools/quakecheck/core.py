@@ -617,15 +617,15 @@ def check_qk103(tree: ast.AST, path: str, pragmas: FilePragmas,
             findings.append(Finding("QK103", path, node.lineno,
                                     node.col_offset, msg))
 
-    # (a) version-churned pltpu names only through pallas_compat
+    # (a) the Mosaic params / grid API only through pallas_compat
     if not is_compat:
         for node in ast.walk(tree):
             if isinstance(node, ast.Attribute) \
                     and node.attr in config.PLTPU_COMPAT_ONLY \
                     and root_name(node.value or node) in ("pltpu",):
                 flag(node, f"direct pltpu.{node.attr} — dispatch through "
-                           f"kernels/pallas_compat.py (the one-file "
-                           f"version seam)")
+                           f"kernels/pallas_compat.py (the one file "
+                           f"that names that API)")
             if isinstance(node, (ast.ImportFrom,)) and node.module \
                     and "pallas" in node.module:
                 for alias in node.names:

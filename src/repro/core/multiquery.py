@@ -1304,6 +1304,15 @@ class BatchedSearchExecutor:
                              * sizes_sel[None, :]).sum()),
             nprobe=plan.nprobe, recall_estimate=plan.recall_est)
 
+    def union_pad(self, n: int, u_pow2: bool = False) -> int:
+        """The union width ``scan_probe_round`` pads ``n`` partitions to:
+        linear ``u_bucket`` steps, or the geometric ladder with
+        ``u_pow2``."""
+        n = max(int(n), 1)
+        if u_pow2:
+            return self.u_bucket * ops._next_pow2(-(-n // self.u_bucket))
+        return max(-(-n // self.u_bucket) * self.u_bucket, 1)
+
     def scan_probe_round(self, q_dev, seq_dev, take: np.ndarray,
                          kept: np.ndarray, k_keep: int, snap=None,
                          impl: Optional[str] = None,
@@ -1345,10 +1354,7 @@ class BatchedSearchExecutor:
                 int(snap.num_partitions))
         prio0 = jnp.zeros((p,), jnp.int32)   # uncapped: no anchor boost
         n_real = max(len(kept), 1)
-        u_pad = max(-(-n_real // self.u_bucket) * self.u_bucket, 1)
-        if u_pow2:
-            u_pad = self.u_bucket * ops._next_pow2(
-                -(-n_real // self.u_bucket))
+        u_pad = self.union_pad(n_real, u_pow2)
         # pack + inert-tail masking on device (no host round trip; the
         # dynamic n_real scalar shares one executable across round sizes)
         sel_dev, qmask_dev = ops.pack_round_masked(

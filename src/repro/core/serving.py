@@ -59,6 +59,7 @@ import numpy as np
 from ..faults import FaultInjector, InjectedFault
 from ..kernels.ref import MASK_DIST
 from ..obs import Observability
+from ..obs.tracing import NO_SPAN, span
 from ..sanitize import TrackedLock, note_guarded, observability_counters
 from . import aps as aps_mod
 from . import multiquery as mq
@@ -74,6 +75,13 @@ __all__ = ["ServingConfig", "ServingRuntime", "QueryResult", "ResultCache",
            "STATUS_SHED", "STATUS_FAILED", "TERMINAL_STATUSES"]
 
 logger = logging.getLogger("repro.serving")
+
+
+def _span(obs, name: str, **args):
+    """A profiler span (``repro.obs.tracing.span``) where observability
+    is on; ``NO_SPAN``, which records nothing, where ``obs`` is None."""
+    return NO_SPAN if obs is None else span(name, **args)
+
 
 # Terminal query statuses (docs/serving.md failure semantics): every
 # admitted query reaches exactly one of these — no query ever vanishes.
@@ -853,11 +861,17 @@ class RoundScheduler:
         # acquisition per round is measurable against a ~100us query
         # (the obs-overhead bench cell gates this path's cost)
         self._obs_walls: List[float] = []
+        self._obs_waits: List[float] = []
         self._obs_parts = 0
         self._obs_vecs = 0
         self._obs_rounds: List[dict] = []
         self._obs_flushes: List[dict] = []
+        # (engine-lock wait, engine-held) seconds of each served call
+        self._obs_served: List[tuple] = []
         self._cal_tick = 0
+        # (scan, wait) seconds of the last round scan: dispatch through
+        # the host pull, and the pull alone
+        self._scan_times = (0.0, 0.0)
 
     def set_degradation(self, target: float,
                         probe_frac: Optional[float]) -> None:
@@ -963,13 +977,17 @@ class RoundScheduler:
         """Run one shared probe round.  Returns False once nothing is in
         flight (all queries retired)."""
         with self._lock:
-            return self._step_locked()
+            if self.obs is None or not self.active:
+                return self._step_locked()
+            with span("scheduler.round") as sp:
+                return self._step_locked(sp)
 
-    def _step_locked(self) -> bool:
+    def _step_locked(self, sp=NO_SPAN) -> bool:
         note_guarded(self, "active")
         rows = self.active
         if not rows:
             return False
+        t_round = self._clock() if self.obs is not None else 0.0
         b = len(rows)
         m = self._m
         seq_mat = np.stack([pq.seq for pq in rows])
@@ -993,6 +1011,9 @@ class RoundScheduler:
             return bool(self.active)
 
         kept = np.unique(seq_mat[base])
+        if sp is not NO_SPAN:
+            sp.set_metadata(rows=b, b_pad=self._row_pad(b), union=len(kept),
+                            u_pad=self.ex.union_pad(len(kept), u_pow2=True))
         p = self.index.levels[0].num_partitions
         in_union = np.zeros(max(int(seq_mat.max()) + 1, p), dtype=bool)
         in_union[kept] = True
@@ -1002,7 +1023,6 @@ class RoundScheduler:
         q_mat = np.stack([pq.q for pq in rows])
         if self.faults is not None:
             self.faults.stall("slow_round")   # injected straggler round
-        t_scan = self._clock()
         scan = self._scan_with_retry(q_mat, seq_mat, take, kept, rows)
         if scan is None:
             # retries exhausted: fail the affected in-flight batch —
@@ -1011,105 +1031,116 @@ class RoundScheduler:
             self._fail_inflight(rows, scanned, within)
             return bool(self.active)
         d, flat, st = scan
+        with _span(self.obs, "scheduler.fold"):
+            # fold into per-query running top-k (host side: rows churn)
+            td = np.stack([pq.td for pq in rows])
+            ti = np.stack([pq.ti for pq in rows])
+            cat_d = np.concatenate([td, d], axis=1)
+            cat_i = np.concatenate([ti, flat], axis=1)
+            order = np.argsort(cat_d, axis=1, kind="stable")[:, :self._k_keep]
+            td = np.take_along_axis(cat_d, order, axis=1)
+            ti = np.take_along_axis(cat_i, order, axis=1)
 
-        # fold into per-query running top-k (host side: rows churn)
-        td = np.stack([pq.td for pq in rows])
-        ti = np.stack([pq.ti for pq in rows])
-        cat_d = np.concatenate([td, d], axis=1)
-        cat_i = np.concatenate([ti, flat], axis=1)
-        order = np.argsort(cat_d, axis=1, kind="stable")[:, :self._k_keep]
-        td = np.take_along_axis(cat_d, order, axis=1)
-        ti = np.take_along_axis(cat_i, order, axis=1)
+            took = take.any(axis=1)
+            takers = [] if self.obs is not None else None
+            for i, pq in enumerate(rows):
+                pq.scanned = scanned[i]
+                pq.td = td[i]
+                pq.ti = ti[i]
+                pq.rounds += int(took[i])
+                if takers is not None and took[i]:
+                    takers.append(pq.qid)
 
-        took = take.any(axis=1)
-        takers = [] if self.obs is not None else None
-        for i, pq in enumerate(rows):
-            pq.scanned = scanned[i]
-            pq.td = td[i]
-            pq.ti = ti[i]
-            pq.rounds += int(took[i])
-            if takers is not None and took[i]:
-                takers.append(pq.qid)
+            self.rounds_run += 1
+            self.round_streams.append(kept)
+            self.partitions_streamed += st["partitions"]
+            self.vectors_streamed += st["vectors"]
+            self.comparisons += st["comparisons"]
+            if self.record_stats:
+                parts, cnts = np.unique(seq_mat[take], return_counts=True)
+                lvl0 = self.index.levels[0]
+                lvl0.stats.ensure(lvl0.num_partitions)
+                lvl0.stats.record_batch(parts, cnts, 0)
 
-        self.rounds_run += 1
-        self.round_streams.append(kept)
-        self.partitions_streamed += st["partitions"]
-        self.vectors_streamed += st["vectors"]
-        self.comparisons += st["comparisons"]
+            finished = ~(within & ~scanned).any(axis=1)
+            statuses = np.full(b, STATUS_OK, dtype=object)
+            now = self._clock()
+            expired = np.asarray([pq.deadline is not None
+                                  and now >= pq.deadline for pq in rows])
+            if self.early_exit or bool((expired & ~finished).any()):
+                # refined APS estimate from the *running* k-th distance —
+                # the early-exit retirement test, and what a budget-expired
+                # query's PARTIAL result reports as the recall it earned
+                kth = td[:, self.k - 1]
+                full = kth < MASK_DIST
+                if self.index.config.metric == "l2":
+                    rho_sq = aps_mod.rho_sq_batch(kth, metric="l2")
+                else:
+                    qn = np.asarray([pq.q_norm_sq for pq in rows])
+                    rho_sq = aps_mod.rho_sq_batch(
+                        kth, metric="ip", q_norm_sq=qn,
+                        max_norm_sq=self.index._max_norm_sq)
+                rho_sq = np.where(full, rho_sq, np.inf)
+                geo_mat = np.stack([pq.geo for pq in rows])
+                cc_mat = np.stack([pq.cc for pq in rows])
+                valid = np.ones((b, m), dtype=bool)
+                valid[:, 0] = False
+                p0, probs = aps_mod.estimate_probs_batch(
+                    geo_mat[:, 0], geo_mat, cc_mat, rho_sq,
+                    self.index._beta_table, valid)
+                r = p0 + np.where(scanned & valid, probs, 0.0).sum(axis=1)
+                if self.early_exit:
+                    for i, pq in enumerate(rows):
+                        if full[i]:
+                            pq.r_est = float(r[i])
+                    finished |= full & (r >= self.target)
+                partial = expired & ~finished
+                if partial.any():
+                    for i in np.nonzero(partial)[0]:
+                        # finite by construction: the refined estimate over
+                        # what was actually scanned, or 0.0 when the top-k
+                        # never filled (the honest lower bound) — never the
+                        # full-plan estimate the query didn't earn
+                        rows[i].r_est = float(r[i]) if full[i] else 0.0
+                    statuses[partial] = STATUS_PARTIAL
+                    self.partials += int(partial.sum())
+                    finished |= partial
+            self._retire(rows, finished, scanned, within, statuses)
         if self.obs is not None:
-            t_now = self._clock()
-            dt_scan = t_now - t_scan
-            self._obs_walls.append(dt_scan)
-            self._obs_parts += int(st["partitions"])
-            self._obs_vecs += int(st["vectors"])
-            # predicted-vs-observed scan cost, sampled every 4th round
-            # (first round always): ``predict_scan_ns`` over the folded
-            # sizes is a numpy pass per call, and roughly-one-sample-
-            # per-flush keeps the rolling error just as live at a
-            # quarter of the cost
-            self._cal_tick += 1
-            if self._cal_tick % 4 == 1:
-                self.obs.calibration.record_scan(
-                    self.index.levels[0].sizes_of(kept), dt_scan)
-            # one metadata record per round — the taker qids are how
-            # spans recover per-round scan events at read time
-            # (QueryTracer.note_rounds); no per-query work here
-            self._obs_rounds.append({
-                "t": t_now, "round": self.rounds_run,
-                "partitions": int(st["partitions"]),
-                "vectors": int(st["vectors"]),
-                "wall_s": dt_scan, "takers": takers})
-        if self.record_stats:
-            parts, cnts = np.unique(seq_mat[take], return_counts=True)
-            lvl0 = self.index.levels[0]
-            lvl0.stats.ensure(lvl0.num_partitions)
-            lvl0.stats.record_batch(parts, cnts, 0)
-
-        finished = ~(within & ~scanned).any(axis=1)
-        statuses = np.full(b, STATUS_OK, dtype=object)
-        now = self._clock()
-        expired = np.asarray([pq.deadline is not None and now >= pq.deadline
-                              for pq in rows])
-        if self.early_exit or bool((expired & ~finished).any()):
-            # refined APS estimate from the *running* k-th distance —
-            # the early-exit retirement test, and what a budget-expired
-            # query's PARTIAL result reports as the recall it earned
-            kth = td[:, self.k - 1]
-            full = kth < MASK_DIST
-            if self.index.config.metric == "l2":
-                rho_sq = aps_mod.rho_sq_batch(kth, metric="l2")
-            else:
-                qn = np.asarray([pq.q_norm_sq for pq in rows])
-                rho_sq = aps_mod.rho_sq_batch(
-                    kth, metric="ip", q_norm_sq=qn,
-                    max_norm_sq=self.index._max_norm_sq)
-            rho_sq = np.where(full, rho_sq, np.inf)
-            geo_mat = np.stack([pq.geo for pq in rows])
-            cc_mat = np.stack([pq.cc for pq in rows])
-            valid = np.ones((b, m), dtype=bool)
-            valid[:, 0] = False
-            p0, probs = aps_mod.estimate_probs_batch(
-                geo_mat[:, 0], geo_mat, cc_mat, rho_sq,
-                self.index._beta_table, valid)
-            r = p0 + np.where(scanned & valid, probs, 0.0).sum(axis=1)
-            if self.early_exit:
-                for i, pq in enumerate(rows):
-                    if full[i]:
-                        pq.r_est = float(r[i])
-                finished |= full & (r >= self.target)
-            partial = expired & ~finished
-            if partial.any():
-                for i in np.nonzero(partial)[0]:
-                    # finite by construction: the refined estimate over
-                    # what was actually scanned, or 0.0 when the top-k
-                    # never filled (the honest lower bound) — never the
-                    # full-plan estimate the query didn't earn
-                    rows[i].r_est = float(r[i]) if full[i] else 0.0
-                statuses[partial] = STATUS_PARTIAL
-                self.partials += int(partial.sum())
-                finished |= partial
-        self._retire(rows, finished, scanned, within, statuses)
+            self._note_round(t_round, kept, st, takers)
         return True
+
+    def _note_round(self, t_round: float, kept: np.ndarray, st: dict,
+                    takers: List[int]) -> None:
+        """Defer one scanned round's observability to ``flush_obs``: the
+        whole round's wall time from ``t_round``, the host's wait on the
+        device, the predicted-vs-observed scan cost, and the round
+        record."""
+        t_now = self._clock()
+        wall = t_now - t_round
+        scan_s, wait_s = self._scan_times
+        self._obs_walls.append(wall)
+        self._obs_waits.append(wait_s)
+        self._obs_parts += int(st["partitions"])
+        self._obs_vecs += int(st["vectors"])
+        # predicted-vs-observed scan cost, sampled every 4th round
+        # (first round always): ``predict_scan_ns`` over the folded
+        # sizes is a numpy pass per call, and roughly-one-sample-per-
+        # flush keeps the rolling error just as live at a quarter of
+        # the cost.  Observed: the scan as the host sees it, dispatch
+        # through the pull of its result
+        self._cal_tick += 1
+        if self._cal_tick % 4 == 1:
+            self.obs.calibration.record_scan(
+                self.index.levels[0].sizes_of(kept), scan_s)
+        # one metadata record per round — the taker qids are how spans
+        # recover per-round scan events at read time
+        # (QueryTracer.note_rounds); no per-query work here
+        self._obs_rounds.append({
+            "t": t_now, "round": self.rounds_run,
+            "partitions": int(st["partitions"]),
+            "vectors": int(st["vectors"]),
+            "wall_s": wall, "wait_s": wait_s, "takers": takers})
 
     # -- fault handling ------------------------------------------------
 
@@ -1117,17 +1148,16 @@ class RoundScheduler:
                    take: np.ndarray, kept: np.ndarray,
                    rows: List[_Pending]):
         b, m = take.shape
+        obs = self.obs is not None
+        t0 = self._clock() if obs else 0.0
         if self.scan_backend == "host":
-            return host_scan_round(
+            out = host_scan_round(
                 self.index, q_mat, seq_mat, take, kept, self._k_keep,
                 q_norm_sq=np.asarray([pq.q_norm_sq for pq in rows]))
-        # pad the active rows on a geometric ladder (b_bucket * 2^i)
-        # so the jitted scan sees O(log B) distinct (B, M) shapes as
-        # the in-flight population grows/shrinks; pad rows carry
-        # take=False (inert under the scan mask)
-        b_pad = self.b_bucket
-        while b_pad < b:
-            b_pad *= 2
+            if obs:    # no device: the host never waits on one
+                self._scan_times = (self._clock() - t0, 0.0)
+            return out
+        b_pad = self._row_pad(b)
         q_pad = q_mat
         if b_pad > b:
             q_pad = np.concatenate(
@@ -1139,17 +1169,37 @@ class RoundScheduler:
                 [take, np.zeros((b_pad - b, m), bool)])
         else:
             seq_pad, take_pad = seq_mat, take
-        d, flat, st = self.ex.scan_probe_round(
-            jnp.asarray(q_pad), jnp.asarray(seq_pad.astype(np.int32)),
-            take_pad, kept, self._k_keep, snap=self._snap, u_pow2=True,
-            seq_host=seq_pad)
-        # the scheduler's running top-k folds on host because the row
-        # set churns every round (admissions/retirements) — one pull
-        # per round over the active rows
-        # quakecheck: allow-sync(per-round fold: host top-k over a churning row set)
-        d = np.asarray(d, dtype=np.float64)[:b]
-        flat = np.asarray(flat, dtype=np.int64)[:b]  # quakecheck: allow-sync(per-round fold)
+        with _span(self.obs, "scan.dispatch") as sp:
+            if sp is not NO_SPAN:
+                sp.set_metadata(b_pad=b_pad, u_pad=self.ex.union_pad(
+                    len(kept), u_pow2=True))
+            d, flat, st = self.ex.scan_probe_round(
+                jnp.asarray(q_pad), jnp.asarray(seq_pad.astype(np.int32)),
+                take_pad, kept, self._k_keep, snap=self._snap, u_pow2=True,
+                seq_host=seq_pad)
+        t1 = self._clock() if obs else 0.0
+        with _span(self.obs, "scan.wait"):
+            # the scheduler's running top-k folds on host because the
+            # row set churns every round (admissions/retirements) — one
+            # pull per round over the active rows
+            # quakecheck: allow-sync(per-round fold: host top-k over a churning row set)
+            d = np.asarray(d, dtype=np.float64)[:b]
+            flat = np.asarray(flat, dtype=np.int64)[:b]  # quakecheck: allow-sync(per-round fold)
+        if obs:
+            t2 = self._clock()
+            self._scan_times = (t2 - t0, t2 - t1)
         return d, flat, st
+
+    def _row_pad(self, b: int) -> int:
+        """The row count a device round scan pads ``b`` active rows to:
+        a geometric ladder (``b_bucket * 2^i``), so the jitted scan sees
+        O(log B) distinct (B, M) shapes as the in-flight population
+        grows and shrinks; pad rows carry take=False (inert under the
+        scan mask)."""
+        b_pad = self.b_bucket
+        while b_pad < b:
+            b_pad *= 2
+        return b_pad
 
     def _scan_with_retry(self, q_mat: np.ndarray, seq_mat: np.ndarray,
                          take: np.ndarray, kept: np.ndarray,
@@ -1276,21 +1326,37 @@ class RoundScheduler:
         with self._lock:
             note_guarded(self, "_obs_rounds")
             walls, self._obs_walls = self._obs_walls, []
+            waits, self._obs_waits = self._obs_waits, []
             rounds, self._obs_rounds = self._obs_rounds, []
             flushes, self._obs_flushes = self._obs_flushes, []
+            served, self._obs_served = self._obs_served, []
             parts, vecs = self._obs_parts, self._obs_vecs
             self._obs_parts = 0
             self._obs_vecs = 0
+        counters, observations = {}, {}
         if walls:
-            self.obs.metrics.update(
-                counters={"scheduler.rounds": len(walls),
-                          "scheduler.partitions_streamed": parts,
-                          "scheduler.vectors_streamed": vecs},
-                observations={"scheduler.round_wall_s": walls})
+            counters = {"scheduler.rounds": len(walls),
+                        "scheduler.partitions_streamed": parts,
+                        "scheduler.vectors_streamed": vecs}
+            observations = {"scheduler.round_wall_s": walls,
+                            "scan.wait_s": waits}
+        if served:
+            observations["serving.engine_wait_s"] = [w for w, _ in served]
+            observations["serving.flush_s"] = [f for _, f in served]
+        if observations:
+            self.obs.metrics.update(counters=counters,
+                                    observations=observations)
         if flushes:
             self.obs.tracer.note_flushes(flushes)
         if rounds:
             self.obs.tracer.note_rounds(rounds)
+
+    def note_served(self, wait_s: float, held_s: float) -> None:
+        """Defer one served call's wait for the engine lock and its
+        engine-held seconds (``ServingRuntime._served``) to the next
+        ``flush_obs``."""
+        with self._lock:
+            self._obs_served.append((wait_s, held_s))
 
     def has_active(self) -> bool:
         with self._lock:
@@ -1695,8 +1761,9 @@ class ServingRuntime:
             due = bool(self._queue) and (
                 self._clock() - self._queue[0][2] >= deadline)
         if due:
+            wait = self._engine_wait()
             with self._engine_lock:
-                self._drain_engine()
+                self._served(wait, self._drain_engine)
         return due
 
     def _ticker_loop(self) -> None:
@@ -1753,16 +1820,49 @@ class ServingRuntime:
     def flush(self) -> None:
         """Coalesce the queue into one executor batch, admit it to the
         riding scheduler, and advance in-flight rounds."""
+        wait = self._engine_wait()
         with self._engine_lock:
-            self._flush_engine()
+            self._served(wait, self._flush_engine)
 
-    def _flush_engine(self) -> None:
+    def _engine_wait(self):
+        """Before a served call takes the engine lock: with observability
+        on, open its ``serving.engine_wait`` span and return it with the
+        clock's reading; None otherwise."""
+        if self.obs is None:
+            return None
+        sp = span("serving.engine_wait")
+        sp.__enter__()
+        return sp, self._clock()
+
+    def _served(self, wait, body: Callable[[], int]) -> None:
+        """Run ``body`` (``_flush_engine`` or ``_drain_engine``) as one
+        served call, under the engine lock its caller has just taken
+        after ``wait = self._engine_wait()``: closes the wait's span,
+        runs ``body`` inside a ``serving.flush`` span (``n``: the queries
+        it took off the queue), and defers both intervals to the
+        scheduler's ``flush_obs``."""
+        if wait is None:
+            body()
+            return
+        sp, t0 = wait
+        t1 = self._clock()
+        sp.__exit__(None, None, None)
+        with span("serving.flush") as fs:
+            fs.set_metadata(n=body())
+        self.scheduler.note_served(t1 - t0, self._clock() - t1)
+
+    def _flush_engine(self) -> int:
+        """Admit the queue as one batch, run ``interleave_rounds`` rounds
+        and collect; returns how many queries it admitted."""
+        obs = self.obs is not None
         with self._lock:
             note_guarded(self, "_queue")
             batch = list(self._queue)
             self._queue.clear()
             overflow = self._overflow_since_flush
             self._overflow_since_flush = False
+            # a query's queue wait ends here, as it leaves the queue
+            t_taken = self._clock() if obs else 0.0
         if self.cfg.govern:
             self._govern(len(batch), overflow)
         if batch:
@@ -1770,7 +1870,6 @@ class ServingRuntime:
                     and self.executor._fingerprint()
                     != self.scheduler.epoch_key()):
                 self.scheduler.drain()     # out-of-band mutation barrier
-            self._ensure_radius()
             qids = [t[0] for t in batch]
             qs = np.stack([t[1] for t in batch])
             ts = [t[2] for t in batch]
@@ -1781,23 +1880,28 @@ class ServingRuntime:
                     self._admit_gen[qid] = gen
                 if self.cfg.record_admissions:
                     self._admission_log.append(("q", tuple(qids)))
-            self.scheduler.admit(qs, qids, ts, deadlines=dls)
-            if self.obs is not None:
+            t_plan = self._clock() if obs else 0.0
+            with _span(self.obs, "planner.plan", n=len(batch)):
+                self._ensure_radius()
+                self.scheduler.admit(qs, qids, ts, deadlines=dls)
+            if obs:
                 # the queue-wait distribution lives in the registry;
                 # the span's admit/flush events are synthesized at read
                 # time from the terminal record's t_submit/batch and
                 # the scheduler's flush metadata — no per-query tracer
                 # work on this path
-                t_adm = self._clock()
-                waits = [t_adm - ft for ft in ts]
+                waits = [t_taken - ft for ft in ts]
                 self.obs.metrics.update(
                     counters={"serving.flushes": 1},
-                    observations={"serving.queue_wait_s": waits})
+                    observations={"serving.queue_wait_s": waits,
+                                  "planner.plan_s":
+                                      (self._clock() - t_plan,)})
             self.maintenance.note_op()
         for _ in range(max(self.cfg.interleave_rounds, 0)):
             if not self.scheduler.step():
                 break
         self._collect()
+        return len(batch)
 
     def _govern(self, batch_fill: int, overflow: bool) -> None:
         """Degradation governor (docs/serving.md): under sustained queue
@@ -1855,53 +1959,58 @@ class ServingRuntime:
         Drains are also where read-only streams get their maintenance
         check: without it the access-shift trigger (read-skew drift) and
         the op-budget backstop could only ever fire on a write barrier."""
+        wait = self._engine_wait()
         with self._engine_lock:
-            self._drain_engine()
+            self._served(wait, self._drain_engine)
         self.maybe_maintain()
 
-    def _drain_engine(self) -> None:
-        self._flush_engine()
+    def _drain_engine(self) -> int:
+        n = self._flush_engine()
         self.scheduler.drain()
         self._collect()
+        return n
 
     def _collect(self) -> None:
-        if self.obs is not None:
-            # deferred round events first, so a span that completes in
-            # this pass still reads admit -> flush -> round* -> done
-            self.scheduler.flush_obs()
-        done_lat, done_events = [], []
-        t_done = self._clock() if self.obs is not None else 0.0
-        for qid, res, q, footprint in self.scheduler.take_done():
-            with self._lock:
-                note_guarded(self, "results")
-                self.results[qid] = res
-                self._status_counts[res.status] += 1
-                gen = self._admit_gen.pop(qid, None)
-                cache_on = (self.cache is not None
-                            and not self._cache_disabled)
+        with _span(self.obs, "serving.collect") as sp:
             if self.obs is not None:
-                done_lat.append(res.latency_s)
-                # one compact DONE_FIELDS tuple per query — the span's
-                # admit/flush/round events are synthesized at read time
-                # from t_submit/batch and the scheduler metadata
-                done_events.append((
-                    qid, t_done, res.status, res.rounds, res.nprobe,
-                    float(res.recall_estimate), res.latency_s,
-                    res.t_submit, res.batch))
-            # only OK results enter the cache: PARTIAL top-k is whatever
-            # the budget allowed (serving it to a later identical query
-            # would silently repeat the degradation), FAILED has no data
-            if cache_on and res.status == STATUS_OK and q is not None:
-                self._cache_guarded(
-                    self.cache.put, q, self.cfg.k, res.ids, res.dists,
-                    footprint, nprobe=res.nprobe,
-                    recall_estimate=res.recall_estimate, gen=gen)
-        if self.obs is not None and done_events:
-            # batched post-loop recording: one registry and one tracer
-            # acquisition per collect pass, not per completed query
-            self.obs.metrics.update(
-                observations={"serving.latency_s": done_lat})
-            self.obs.tracer.close_many(done_events)
+                # deferred round events first, so a span that completes in
+                # this pass still reads admit -> flush -> round* -> done
+                self.scheduler.flush_obs()
+            done_lat, done_events = [], []
+            t_done = self._clock() if self.obs is not None else 0.0
+            done = self.scheduler.take_done()
+            sp.set_metadata(done=len(done))
+            for qid, res, q, footprint in done:
+                with self._lock:
+                    note_guarded(self, "results")
+                    self.results[qid] = res
+                    self._status_counts[res.status] += 1
+                    gen = self._admit_gen.pop(qid, None)
+                    cache_on = (self.cache is not None
+                                and not self._cache_disabled)
+                if self.obs is not None:
+                    done_lat.append(res.latency_s)
+                    # one compact DONE_FIELDS tuple per query — the span's
+                    # admit/flush/round events are synthesized at read time
+                    # from t_submit/batch and the scheduler metadata
+                    done_events.append((
+                        qid, t_done, res.status, res.rounds, res.nprobe,
+                        float(res.recall_estimate), res.latency_s,
+                        res.t_submit, res.batch))
+                # only OK results enter the cache: PARTIAL top-k is whatever
+                # the budget allowed (serving it to a later identical query
+                # would silently repeat the degradation), FAILED has no data
+                if cache_on and res.status == STATUS_OK and q is not None:
+                    self._cache_guarded(
+                        self.cache.put, q, self.cfg.k, res.ids, res.dists,
+                        footprint, nprobe=res.nprobe,
+                        recall_estimate=res.recall_estimate, gen=gen)
+            if self.obs is not None and done_events:
+                # batched post-loop recording: one registry and one tracer
+                # acquisition per collect pass, not per completed query
+                self.obs.metrics.update(
+                    observations={"serving.latency_s": done_lat})
+                self.obs.tracer.close_many(done_events)
 
     def result(self, qid: int) -> Optional[QueryResult]:
         """The query's result, or None while it is still in flight."""
@@ -1912,8 +2021,9 @@ class ServingRuntime:
     # -- writes (barriers) --------------------------------------------
 
     def submit_insert(self, x: np.ndarray, ids: np.ndarray) -> None:
+        wait = self._engine_wait()
         with self._engine_lock:
-            self._drain_engine()
+            self._served(wait, self._drain_engine)
             if self.durability is not None:
                 # write-ahead, in engine-lock (= admission) order: if the
                 # append crashes, the op was never applied — recovery
@@ -1928,8 +2038,9 @@ class ServingRuntime:
             self._after_write()
 
     def submit_delete(self, ids: np.ndarray) -> int:
+        wait = self._engine_wait()
         with self._engine_lock:
-            self._drain_engine()
+            self._served(wait, self._drain_engine)
             if self.durability is not None:
                 self.durability.log_delete(ids)
             removed = self.index.delete(ids)

@@ -26,6 +26,13 @@ wall-clock dates (QK401, docs/static_analysis.md).
 ``QueryTracer._lock`` sits next-to-innermost in
 ``repro.sanitize.LOCK_ORDER``: recording is legal under any runtime
 lock and acquires nothing else.
+
+:func:`span` is the other half: a named interval on the profiler's
+timeline (``jax.profiler.TraceAnnotation``), the clock the device ops
+of a trace are aligned to.  The serving path opens one around each of
+its steps (docs/observability.md, "Profiler spans"); with no profiler
+running it is ``NO_SPAN`` and costs a check.  Spans take no Python
+lock.
 """
 from __future__ import annotations
 
@@ -33,9 +40,11 @@ import json
 from collections import deque
 from typing import Dict, List, Mapping
 
+from jax.profiler import TraceAnnotation
+
 from ..sanitize import TrackedLock, note_guarded
 
-__all__ = ["DONE_FIELDS", "QueryTracer"]
+__all__ = ["DONE_FIELDS", "NO_SPAN", "QueryTracer", "span"]
 
 # field order of a compact terminal record (a plain tuple: building a
 # dict per query on the serving hot path is measurable; building nine
@@ -43,6 +52,35 @@ __all__ = ["DONE_FIELDS", "QueryTracer"]
 # ``spans()``
 DONE_FIELDS = ("qid", "t", "status", "rounds", "nprobe",
                "recall_estimate", "latency_s", "t_submit", "batch")
+
+
+def span(name: str, **args):
+    """A profiler span named ``name`` with ``args`` as its stats, on the
+    host's TraceMe timeline beside JAX's own events (compiles, dispatch);
+    ``set_metadata(**more)`` adds stats before it closes.  With no
+    profiler running it is ``NO_SPAN``: a TraceMe opened then records
+    nothing either, and a site can skip computing stats for it."""
+    if not TraceAnnotation.is_enabled():
+        return NO_SPAN
+    return TraceAnnotation(name, **args)
+
+
+class _NoSpan:
+    """What a span site uses when observability is off: records nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set_metadata(self, **args) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
 
 
 def _json_default(o):
@@ -96,9 +134,11 @@ class QueryTracer:
 
     def note_rounds(self, recs) -> None:
         """Record round metadata (``{"t", "round", "partitions",
-        "vectors", "wall_s", "takers"}``) — one per scheduler round;
-        ``takers`` lists the qids that took cells, which is how spans
-        recover their per-round scan events."""
+        "vectors", "wall_s", "wait_s", "takers"}``) — one per scheduler
+        round: ``wall_s`` the whole round, ``wait_s`` the host's wait on
+        the device's result within it; ``takers`` lists the qids that
+        took cells, which is how spans recover their per-round scan
+        events."""
         with self._lock:
             note_guarded(self, "_rounds")
             self._rounds.extend(recs)
@@ -144,7 +184,8 @@ class QueryTracer:
                                "round": rr["round"],
                                "partitions": rr["partitions"],
                                "vectors": rr["vectors"],
-                               "wall_s": rr["wall_s"]})
+                               "wall_s": rr["wall_s"],
+                               "wait_s": rr["wait_s"]})
             events.append({"e": "done", "t": t, "status": status,
                            "rounds": rounds_n, "nprobe": nprobe,
                            "recall_estimate": recall_est,

@@ -15,7 +15,10 @@ Pins the subsystem's contracts:
     results, including under admission-log replay;
   * trace spans — compact terminal records expand to full
     admit -> flush -> round* -> done event lists; cache hits and shed
-    queries get single-instant spans; ring eviction is accounted.
+    queries get single-instant spans; ring eviction is accounted;
+  * profiler spans — the served path's steps on the profiler's
+    timeline, nested flush > round > scan/fold, counted as the
+    registry's histograms count them, and none with metrics off.
 """
 import json
 import threading
@@ -232,6 +235,9 @@ GOLDEN_KEYS = (
     "scheduler.rounds", "scheduler.partitions_streamed",
     "scheduler.vectors_streamed", "scheduler.round_wall_s.count",
     "scheduler.round_wall_s.p50",
+    # the served path's steps, on the runtime's clock
+    "serving.engine_wait_s.count", "serving.flush_s.count",
+    "planner.plan_s.count", "scan.wait_s.count",
     # calibration (LatencyModel predicted vs observed)
     "calibration.latency.samples", "calibration.latency.rel_err",
     "calibration.latency.predicted_s.p50",
@@ -465,3 +471,176 @@ def test_calibration_without_model_is_inert():
     cal.record_scan([100], 0.001)
     assert cal.latency_error() is None
     assert reg.counter("calibration.latency.samples") == 0
+
+
+# ---------------------------------------------------------------------------
+# profiler spans (docs/observability.md, "Profiler spans")
+# ---------------------------------------------------------------------------
+
+PROGRAM_SPANS = ("serving.engine_wait", "serving.flush", "planner.plan",
+                 "scheduler.round", "scan.dispatch", "scan.wait",
+                 "scheduler.fold", "serving.collect")
+
+
+def _profiled(log_dir, fn):
+    """Run ``fn`` under the JAX profiler; returns its result and the
+    program spans recorded, per host thread:
+    ``[[(name, start_ns, end_ns, stats), ...], ...]``."""
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(log_dir.rglob("*.xplane.pb"))
+    threads = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    {k: v for k, v in e.stats})
+                   for e in line.events if e.name in PROGRAM_SPANS]
+            if evs:
+                threads.append(evs)
+    return out, threads
+
+
+def _serve_mixed(ds, metrics):
+    """Flushes by size, a drain, a write barrier and a drain with
+    nothing queued, on the device scan backend."""
+    rt = ServingRuntime(build(ds), serve_cfg(
+        scan_backend="device", impl="jnp", ticker=False, metrics=metrics))
+    q = datasets.queries_near(ds, 20, seed=36).astype(np.float32)
+    rt.submit_batch(q[:4])
+    rt.drain()                     # warm: the scan compiles here
+
+    def serve():
+        rt.submit_batch(q[4:16])   # one flush of 8 by size
+        rt.drain()                 # the other 4
+        rt.submit_insert(ds.vectors[:3] + 0.01, np.arange(93_000, 93_003))
+        rt.submit_batch(q[16:])
+        rt.drain()
+        rt.drain()                 # nothing queued, nothing in flight
+    return rt, serve
+
+
+def _inside(inner, outers):
+    return any(o[1] <= inner[1] and inner[2] <= o[2] for o in outers)
+
+
+def test_profiler_spans_nest_and_match_registry(ds, tmp_path):
+    """Every span of the served path lands on the profiler's timeline:
+    scan and fold inside their round, rounds and plans inside a served
+    flush; the registry's histograms count what the spans count."""
+    rt, serve = _serve_mixed(ds, True)
+    before = rt.metrics_snapshot()
+    _, threads = _profiled(tmp_path, serve)
+    after = rt.metrics_snapshot()
+    rt.close()
+
+    def delta(key):
+        return after.get(key, 0) - before.get(key, 0)
+
+    names = {ev[0] for evs in threads for ev in evs}
+    assert names == set(PROGRAM_SPANS)
+    count = {n: sum(ev[0] == n for evs in threads for ev in evs)
+             for n in PROGRAM_SPANS}
+    for evs in threads:
+        by = {n: [ev for ev in evs if ev[0] == n] for n in PROGRAM_SPANS}
+        for inner in ("scan.dispatch", "scan.wait", "scheduler.fold"):
+            assert all(_inside(ev, by["scheduler.round"])
+                       for ev in by[inner]), inner
+        for inner in ("scheduler.round", "planner.plan", "serving.collect"):
+            assert all(_inside(ev, by["serving.flush"])
+                       for ev in by[inner]), inner
+        for ev in by["scheduler.round"]:
+            st = ev[3]
+            assert 1 <= st["rows"] <= st["b_pad"]
+            assert 1 <= st["union"] <= st["u_pad"]
+        for ev in by["scan.dispatch"]:
+            assert set(ev[3]) == {"b_pad", "u_pad"}
+    # every query of the window left the queue in one served flush
+    assert sum(ev[3]["n"] for evs in threads for ev in evs
+               if ev[0] == "serving.flush") == 16
+    # five served calls ran, three of them with queries to plan
+    assert count["serving.flush"] == count["serving.engine_wait"] == 5
+    assert delta("serving.flush_s.count") == 5
+    assert delta("serving.engine_wait_s.count") == 5
+    assert delta("serving.flushes") == count["planner.plan"] == 3
+    assert delta("planner.plan_s.count") == 3
+    rounds = delta("scheduler.rounds")
+    assert rounds >= 3
+    assert count["scheduler.round"] == count["scan.wait"] == rounds
+    assert delta("scheduler.round_wall_s.count") == rounds
+    assert delta("scan.wait_s.count") == rounds
+    assert 0.0 < delta("scan.wait_s.sum") \
+        < delta("scheduler.round_wall_s.sum")
+
+
+def test_metrics_off_records_no_span(ds, tmp_path):
+    """metrics=False: the served path opens no span at all."""
+    rt, serve = _serve_mixed(ds, False)
+    _, threads = _profiled(tmp_path, serve)
+    rt.close()
+    assert threads == []
+
+
+def test_queue_wait_ends_before_planning(ds):
+    """A query's queue wait ends as it leaves the queue; planning is
+    counted once, in ``planner.plan_s``."""
+    now = [0.0]
+    rt = ServingRuntime(build(ds), serve_cfg(ticker=False),
+                        clock=lambda: now[0])
+    ensure = rt._ensure_radius
+
+    def slow_plan():
+        now[0] += 5.0
+        ensure()
+    rt._ensure_radius = slow_plan
+    q = datasets.queries_near(ds, 8, seed=37).astype(np.float32)
+    rt.submit_batch(q)             # one flush by size
+    ms = rt.metrics_snapshot()
+    rt.close()
+    assert ms["serving.queue_wait_s.count"] == 8
+    assert ms["serving.queue_wait_s.max"] == 0.0
+    assert ms["planner.plan_s.count"] == 1
+    assert ms["planner.plan_s.sum"] == 5.0
+
+
+def test_round_intervals_split_scan_from_fold(ds):
+    """The round's wall time is the whole round; the calibration sample
+    is the scan alone, dispatch through the pull; the round record
+    carries the host's wait on the device."""
+    now = [0.0]
+    rt = ServingRuntime(build(ds), serve_cfg(
+        scan_backend="device", impl="jnp", ticker=False),
+        clock=lambda: now[0])
+    sched = rt.scheduler
+    scan, retire = sched.ex.scan_probe_round, sched._retire
+
+    def slow_scan(*a, **kw):
+        now[0] += 1.0
+        return scan(*a, **kw)
+
+    def slow_retire(*a, **kw):
+        now[0] += 10.0
+        return retire(*a, **kw)
+    sched.ex.scan_probe_round = slow_scan
+    sched._retire = slow_retire
+    q = datasets.queries_near(ds, 8, seed=38).astype(np.float32)
+    rt.submit_batch(q)
+    rt.drain()
+    ms = rt.metrics_snapshot()
+    rounds = [rr for rr in rt.obs.tracer._rounds]
+    rt.close()
+    assert ms["scheduler.rounds"] == len(rounds) >= 1
+    assert all(rr["wall_s"] == 11.0 and rr["wait_s"] == 0.0
+               for rr in rounds)
+    assert ms["scheduler.round_wall_s.mean"] == 11.0
+    assert ms["scan.wait_s.max"] == 0.0
+    assert ms["calibration.latency.samples"] >= 1
+    assert ms["calibration.latency.observed_s.min"] == 1.0
+    assert ms["calibration.latency.observed_s.max"] == 1.0

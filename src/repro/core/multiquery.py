@@ -819,7 +819,9 @@ def _round_windows(n_max: int, rounds: Optional[int] = None):
     lacks), then doubling windows so the hard tail amortizes dispatch.
     A ``rounds`` budget merges the tail into the final round, so the
     windows always cover the full planned list — ``rounds=1`` degenerates
-    to one fixed-plan scan."""
+    to one fixed-plan scan.  The served ``RoundScheduler`` gives these
+    windows only to rows that can retire early (early exit or a latency
+    budget); a fixed-plan row there takes its whole plan as one window."""
     wins, c0, w = [], 0, 1
     while c0 < n_max:
         wins.append((c0, min(c0 + w, n_max)))

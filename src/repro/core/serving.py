@@ -794,6 +794,15 @@ class RoundScheduler:
     With ``early_exit=False`` every query consumes exactly its fixed
     plan, so results are independent of how admission interleaved with
     rounds — the runtime's coalescing-determinism contract.
+
+    Windows exist for the checks between rounds: the early-exit test and
+    the latency budget's ``PARTIAL`` retirement.  A row that is subject
+    to neither (``early_exit=False`` and no deadline) gets its whole
+    effective plan as one window and finishes in the round that admits
+    it; a row that is subject to either gets ``mq._round_windows``'
+    geometric windows (1, 1, 1, 2, 4, ... probes, merged under a
+    ``rounds`` budget).  Both kinds share a round's union and ride it
+    alike.  Counted in ``one_window_rows`` / ``windowed_rows``.
     """
 
     def __init__(self, executor: "mq.BatchedSearchExecutor", k: int,
@@ -848,6 +857,10 @@ class RoundScheduler:
         self.partitions_streamed = 0
         self.vectors_streamed = 0
         self.comparisons = 0
+        # admitted rows by the windows they got: the whole plan as one
+        # window, or ``_round_windows``' geometric windows
+        self.one_window_rows = 0
+        self.windowed_rows = 0
         # failure / degradation telemetry
         self.partials = 0           # budget-expired retirements
         self.failures = 0           # FAILED retirements
@@ -864,6 +877,8 @@ class RoundScheduler:
         self._obs_waits: List[float] = []
         self._obs_parts = 0
         self._obs_vecs = 0
+        self._obs_one_window = 0
+        self._obs_windowed = 0
         self._obs_rounds: List[dict] = []
         self._obs_flushes: List[dict] = []
         # (engine-lock wait, engine-held) seconds of each served call
@@ -917,11 +932,7 @@ class RoundScheduler:
                     "before mutating the index (the runtime's write "
                     "barrier does this)")
             self._epoch_key = fp
-            self._snap = snap
-            self._rerank = (snap is not None and snap.scales is not None
-                            and self.ex.int8_rerank
-                            and self.ex._host_f32 is not None)
-            self._k_keep = 2 * self.k if self._rerank else self.k
+            self._bind_snapshot(snap)
             rplan = mq.plan_rounds(self.index, q, self.k, self.target,
                                    planner=self.ex.planner,
                                    cache=self.ex.planner_cache,
@@ -943,6 +954,7 @@ class RoundScheduler:
                 self._obs_flushes.append(
                     {"batch": batch_id, "t": now, "n": b})
             eff_counts = []
+            windowed = 0
             for i in range(b):
                 count = int(rplan.counts[i])
                 if self.probe_frac is not None:
@@ -951,17 +963,30 @@ class RoundScheduler:
                     # the serving-layer union_cap analog)
                     count = max(1, int(np.ceil(count * self.probe_frac)))
                 eff_counts.append(count)
+                # a row that cannot retire early (no early exit, no
+                # latency budget) consumes its whole plan whatever the
+                # windows: one window saves it a launch-and-sync gap per
+                # window and finishes it in the round that admits it
+                if self.early_exit or dls[i] is not None:
+                    wins = mq._round_windows(count, self.round_budget)
+                    windowed += 1
+                else:
+                    wins = [(0, count)]
                 self.active.append(_Pending(
                     qid=int(qids[i]), q=q[i], q_norm_sq=float(qn[i]),
                     seq=rplan.seq[i], count=count,
                     geo=rplan.geo[i], cc=rplan.cc[i],
-                    wins=mq._round_windows(count, self.round_budget),
-                    win_ptr=0, scanned=np.zeros(m, dtype=bool),
+                    wins=wins, win_ptr=0, scanned=np.zeros(m, dtype=bool),
                     r_est=float(rplan.recall_est[i]),
                     td=np.full(self._k_keep, MASK_DIST, dtype=np.float64),
                     ti=np.full(self._k_keep, -1, dtype=np.int64),
                     t_submit=float(ts[i]), batch=batch_id,
                     deadline=None if dls[i] is None else float(dls[i])))
+            self.one_window_rows += b - windowed
+            self.windowed_rows += windowed
+            if self.obs is not None:
+                self._obs_one_window += b - windowed
+                self._obs_windowed += windowed
             self.plan_footprints.append(
                 np.unique(np.concatenate(
                     [rplan.seq[i][:eff_counts[i]] for i in range(b)])))
@@ -970,6 +995,48 @@ class RoundScheduler:
                 lvl0.stats.ensure(lvl0.num_partitions)
                 lvl0.stats.record_batch(np.zeros(0, np.int64),
                                         np.zeros(0), b)
+
+    def _bind_snapshot(self, snap) -> None:
+        """Point round scans at ``snap`` (None on the host backend):
+        whether they re-rank int8 candidates exactly, and so how many
+        candidates a round keeps."""
+        self._snap = snap
+        self._rerank = (snap is not None and snap.scales is not None
+                        and self.ex.int8_rerank
+                        and self.ex._host_f32 is not None)
+        self._k_keep = 2 * self.k if self._rerank else self.k
+
+    def warm_scans(self) -> int:
+        """Compile the device round scan, before traffic, for the first
+        row bucket at every union width a round of it can take.  A
+        fixed-plan flush scans its whole plan union in one round, so each
+        flush meets one width set by its plans, and a width first met
+        under traffic would compile under the engine lock.  Returns the
+        number of widths scanned (0 on the host backend)."""
+        if self.scan_backend != "device":
+            return 0
+        with self._lock:
+            self._bind_snapshot(self.ex.snapshot())
+            p = self.index.levels[0].num_partitions
+            m = mq._aps_candidate_budget(self.index)
+            b = self.b_bucket
+            # rows of consecutive partition ids (wrapping at p): the first
+            # n cells in row order are n distinct partitions
+            seq = (np.arange(b)[:, None] * m + np.arange(m)[None, :]) % p
+            q = np.zeros((b, self.index.dim), np.float32)
+            most = min(p, b * m)
+            top = self.ex.union_pad(most, u_pow2=True)
+            u = self.ex.union_pad(1, u_pow2=True)
+            widths = 0
+            while True:
+                n = min(u, most)
+                take = np.zeros((b, m), dtype=bool)
+                take.flat[:n] = True
+                self._scan_once(q, seq, take, np.arange(n), [])
+                widths += 1
+                if u >= top:
+                    return widths
+                u *= 2
 
     # -- rounds --------------------------------------------------------
 
@@ -1331,8 +1398,11 @@ class RoundScheduler:
             flushes, self._obs_flushes = self._obs_flushes, []
             served, self._obs_served = self._obs_served, []
             parts, vecs = self._obs_parts, self._obs_vecs
+            one, wnd = self._obs_one_window, self._obs_windowed
             self._obs_parts = 0
             self._obs_vecs = 0
+            self._obs_one_window = 0
+            self._obs_windowed = 0
         counters, observations = {}, {}
         if walls:
             counters = {"scheduler.rounds": len(walls),
@@ -1340,10 +1410,13 @@ class RoundScheduler:
                         "scheduler.vectors_streamed": vecs}
             observations = {"scheduler.round_wall_s": walls,
                             "scan.wait_s": waits}
+        if flushes:
+            counters["scheduler.one_window_rows"] = one
+            counters["scheduler.windowed_rows"] = wnd
         if served:
             observations["serving.engine_wait_s"] = [w for w, _ in served]
             observations["serving.flush_s"] = [f for _, f in served]
-        if observations:
+        if counters or observations:
             self.obs.metrics.update(counters=counters,
                                     observations=observations)
         if flushes:
@@ -1386,6 +1459,8 @@ class RoundScheduler:
                     len(f) for f in self.plan_footprints)),
                 "vectors_streamed": self.vectors_streamed,
                 "comparisons": self.comparisons,
+                "one_window_rows": self.one_window_rows,
+                "windowed_rows": self.windowed_rows,
                 "partials": self.partials,
                 "failures": self.failures,
                 "failed_batches": self.failed_batches,
@@ -1538,11 +1613,12 @@ class ServingRuntime:
     def stage(self) -> None:
         """Stage the device snapshot now, as a server does before it
         takes traffic, rather than at the first flush: writes that arrive
-        before any query then reach it as deltas.  A no-op on the host
-        scan backend."""
+        before any query then reach it as deltas.  Also compiles the
+        round scan for flushes of up to ``b_bucket`` rows at every union
+        width (``RoundScheduler.warm_scans``).  A no-op on the host scan
+        backend."""
         with self._engine_lock:
-            if self.scheduler.scan_backend == "device":
-                self.executor.snapshot()
+            self.scheduler.warm_scans()
 
     def close(self) -> None:
         """Stop the deadline ticker (idempotent).  Queued / in-flight
@@ -2202,6 +2278,8 @@ class ServingRuntime:
             if planned else 0.0,
             "vectors_streamed": sch["vectors_streamed"],
             "comparisons": sch["comparisons"],
+            "one_window_rows": sch["one_window_rows"],
+            "windowed_rows": sch["windowed_rows"],
             "partials": sch["partials"],
             "failures": sch["failures"],
             "failed_batches": sch["failed_batches"],

@@ -235,6 +235,7 @@ GOLDEN_KEYS = (
     "scheduler.rounds", "scheduler.partitions_streamed",
     "scheduler.vectors_streamed", "scheduler.round_wall_s.count",
     "scheduler.round_wall_s.p50",
+    "scheduler.one_window_rows", "scheduler.windowed_rows",
     # the served path's steps, on the runtime's clock
     "serving.engine_wait_s.count", "serving.flush_s.count",
     "planner.plan_s.count", "scan.wait_s.count",

@@ -145,13 +145,19 @@ def test_riding_footprint_invariant(ds):
 def test_late_admission_rides_in_flight_rounds(ds):
     """A batch admitted while another is mid-rounds joins its remaining
     rounds: the footprint invariant holds and total streams stay at or
-    under the per-batch sum."""
+    under the per-batch sum.  The queries carry a latency budget (one
+    that never expires here), so they keep the geometric windows and
+    stay in flight past their first round."""
     idx = build(ds)
     rt = ServingRuntime(idx, ServingConfig(
         k=10, flush_size=16, interleave_rounds=1, rounds=4,
         maint_min_ops=10 ** 9))
-    rt.submit_batch(datasets.queries_near(ds, 16, seed=7))   # flushes+steps
-    rt.submit_batch(datasets.queries_near(ds, 16, seed=8))   # rides
+    budget = 3600.0
+    rt.submit_batch(datasets.queries_near(ds, 16, seed=7),
+                    deadline_s=budget)                       # flushes+steps
+    assert rt.scheduler.has_active()
+    rt.submit_batch(datasets.queries_near(ds, 16, seed=8),
+                    deadline_s=budget)                       # rides
     rt.drain()
     sch = rt.scheduler
     streamed = np.concatenate(sch.round_streams)
@@ -186,6 +192,162 @@ def test_results_exact_over_planned_footprint(ds):
         # partitions (exactness within the scanned footprint)
         assert got <= set(ids.tolist())
         assert res.nprobe >= 1 and res.rounds >= 1
+
+
+# ---------------------------------------------------------------------------
+# Round windows: fixed-plan rows run as one window
+# ---------------------------------------------------------------------------
+
+def _spy_footprints(rt):
+    """Record each finished query's planned footprint as the scheduler
+    hands it to the runtime (qid -> partition ids)."""
+    seen = {}
+    take = rt.scheduler.take_done
+
+    def spy():
+        done = take()
+        for qid, _res, _q, footprint in done:
+            if footprint is not None:
+                seen[qid] = np.asarray(footprint)
+        return done
+    rt.scheduler.take_done = spy
+    return seen
+
+
+def _assert_footprint_exact(rt, qids, queries, footprints):
+    for qid, q in zip(qids, queries):
+        res = rt.result(qid)
+        assert res is not None and res.status == "OK"
+        assert res.nprobe == len(footprints[qid])
+        want = _footprint_topk(rt.index, q, footprints[qid], 10)
+        assert set(int(i) for i in res.ids if i >= 0) == set(want.tolist())
+
+
+@pytest.mark.parametrize("backend", ["host", "device"])
+def test_fixed_plan_rows_finish_in_their_flush(ds, backend):
+    """With no early exit and no budget, every admitted row takes its
+    whole plan as one window: a flush that runs one round leaves nothing
+    in flight, and each result is the exact top-k over its plan."""
+    rt = ServingRuntime(build(ds), ServingConfig(
+        k=10, flush_size=8, interleave_rounds=1, scan_backend=backend,
+        ticker=False, maint_min_ops=10 ** 9))
+    footprints = _spy_footprints(rt)
+    q = datasets.queries_near(ds, 16, seed=40).astype(np.float32)
+    qids = []
+    for lo in (0, 8):
+        qids += rt.submit_batch(q[lo:lo + 8])      # flush by size
+        assert not rt.scheduler.has_active()
+    st = rt.stats()
+    assert st["rounds_run"] == 2 and st["in_flight"] == 0
+    assert st["one_window_rows"] == 16 and st["windowed_rows"] == 0
+    _assert_footprint_exact(rt, qids, q, footprints)
+    rt.close()
+
+
+@pytest.mark.parametrize("backend", ["host", "device"])
+def test_submit_deadline_flush_strands_no_rows(ds, backend):
+    """A flush that ``submit_query`` triggers because the oldest queued
+    query is past the flush deadline runs one round and returns; with
+    fixed-plan rows that round finishes the batch, so no row waits for
+    a later flush."""
+    now = [0.0]
+    rt = ServingRuntime(build(ds), ServingConfig(
+        k=10, flush_size=64, flush_deadline_ms=50.0, ticker=False,
+        scan_backend=backend, maint_min_ops=10 ** 9),
+        clock=lambda: now[0])
+    footprints = _spy_footprints(rt)
+    q = datasets.queries_near(ds, 4, seed=41).astype(np.float32)
+    qids = [rt.submit_query(x) for x in q[:3]]
+    assert rt.stats()["queue_depth"] == 3 and rt.result(qids[0]) is None
+    now[0] = 0.051
+    qids.append(rt.submit_query(q[3]))          # past the deadline: flush
+    assert not rt.scheduler.has_active()
+    assert rt.stats()["queue_depth"] == 0
+    _assert_footprint_exact(rt, qids, q, footprints)
+    rt.close()
+
+
+@pytest.mark.parametrize("mode", ["early_exit", "budget"])
+@pytest.mark.parametrize("backend", ["host", "device"])
+def test_rows_that_can_retire_early_keep_geometric_windows(ds, backend,
+                                                           mode):
+    """Early exit or a latency budget: each row keeps
+    ``_round_windows``' windows (under the ``rounds`` budget too)."""
+    from repro.core.multiquery import _round_windows
+    for rounds in (None, 3):
+        rt = ServingRuntime(build(ds), ServingConfig(
+            k=10, flush_size=8, interleave_rounds=0, rounds=rounds,
+            early_exit=(mode == "early_exit"), scan_backend=backend,
+            ticker=False, maint_min_ops=10 ** 9))
+        q = datasets.queries_near(ds, 8, seed=42).astype(np.float32)
+        rt.submit_batch(q, deadline_s=3600.0 if mode == "budget" else None)
+        active = rt.scheduler.active
+        assert len(active) == 8
+        for pq in active:
+            assert pq.wins == _round_windows(pq.count, rounds)
+        assert any(len(pq.wins) > 1 for pq in active)
+        st = rt.stats()
+        assert st["windowed_rows"] == 8 and st["one_window_rows"] == 0
+        rt.drain()
+        assert all(rt.result(i).status == "OK" for i in range(8))
+        rt.close()
+
+
+@pytest.mark.parametrize("backend", ["host", "device"])
+def test_mixed_window_population_served_exactly(ds, backend):
+    """Rows with a budget (geometric windows) and rows without (one
+    window) share rounds: every result is the exact top-k over its plan,
+    and the two counters add up to the rows admitted, in ``stats()`` and
+    in ``metrics_snapshot()``."""
+    rt = ServingRuntime(build(ds), ServingConfig(
+        k=10, flush_size=8, interleave_rounds=1, scan_backend=backend,
+        ticker=False, maint_min_ops=10 ** 9))
+    footprints = _spy_footprints(rt)
+    q = datasets.queries_near(ds, 24, seed=43).astype(np.float32)
+    qids = [rt.submit_query(x, deadline_s=3600.0 if j % 3 == 0 else None)
+            for j, x in enumerate(q)]
+    rt.drain()
+    _assert_footprint_exact(rt, qids, q, footprints)
+    st = rt.stats()
+    assert st["windowed_rows"] == 8 and st["one_window_rows"] == 16
+    ms = rt.metrics_snapshot()
+    assert ms["scheduler.windowed_rows"] == 8
+    assert ms["scheduler.one_window_rows"] == 16
+    assert ms["serving.one_window_rows"] + ms["serving.windowed_rows"] \
+        == ms["serving.queries_submitted"] == 24
+    rt.close()
+
+
+def test_stage_warms_every_union_width_a_flush_scans(ds):
+    """``stage()`` scans the first row bucket at every union width, so
+    fixed-plan flushes of up to ``b_bucket`` rows meet no scan shape that
+    would first compile under traffic."""
+    rt = ServingRuntime(build(ds), ServingConfig(
+        k=10, flush_size=16, interleave_rounds=1, scan_backend="device",
+        ticker=False, maint_min_ops=10 ** 9))
+    ex = rt.executor
+    seen = []
+    scan = ex.scan_probe_round
+
+    def spy(q_dev, seq_dev, take, kept, *a, **kw):
+        seen.append((take.shape, ex.union_pad(len(kept), u_pow2=True)))
+        return scan(q_dev, seq_dev, take, kept, *a, **kw)
+    ex.scan_probe_round = spy
+    rt.stage()
+    warmed = set(seen)
+    assert len(warmed) == len(seen) >= 3
+    assert max(u for _, u in warmed) == ex.union_pad(
+        rt.index.levels[0].num_partitions, u_pow2=True)
+    assert rt.stats()["rounds_run"] == 0
+    seen.clear()
+    q = datasets.queries_near(ds, 32, seed=44).astype(np.float32)
+    lo = 0
+    for size in (1, 2, 5, 8, 16):
+        rt.submit_batch(q[lo:lo + size])
+        rt.drain()
+        lo += size
+    assert seen and set(seen) <= warmed
+    rt.close()
 
 
 # ---------------------------------------------------------------------------

@@ -38,7 +38,7 @@ SCRIPT = textwrap.dedent("""
             eng = ShardedQuakeEngine(mesh, EngineConfig(
                 k=10, nprobe=16, part_axes=("data",), batch_axis="model"))
             snap = IndexSnapshot.from_index(
-                idx, pad_partitions_to=eng.n_part_shards)
+                idx, pad_partitions_to=eng.n_part_shards, layout=eng.layout)
         else:
             # unaware: snapshot replicated; only the batch splits
             eng = ShardedQuakeEngine(mesh, EngineConfig(

@@ -317,7 +317,7 @@ def run_sharded(n_total: int = N_TOTAL, dim: int = DIM,
             f"partitions the slot capacity leaves: f_M {cal['f_m']}, cap "
             f"dimension {cal['geometry_dim']}, leave-one-out "
             f"recall@{cal['k']} {cal['recall']:.4f}")
-        data = eng._snap.data
+        data = eng.refresh_snapshot(index).data
         shards = [s.data.nbytes for s in data.addressable_shards]
         log(f"{chips} chip(s): recall@{K} {rec:.4f} over {len(q)} queries "
             f"({dt:.1f}s incl. compile); snapshot {data.shape} "

@@ -72,7 +72,8 @@ def main():
                 ("pod", "data", "model"))
     eng = ShardedQuakeEngine(mesh, EngineConfig(
         k=k, nprobe=16, recall_target=0.9, part_axes=("pod", "data")))
-    snap = eng.shard_snapshot(IndexSnapshot.from_index(idx))
+    snap = eng.shard_snapshot(
+        IndexSnapshot.from_index(idx, layout=eng.layout))
     qs = eng.pad_queries(jnp.asarray(users))
     d_e, i_e, r_est, nprobe = eng.search_adaptive(qs, snap)   # compile
     t0 = time.perf_counter()
@@ -89,7 +90,8 @@ def main():
     eng8 = ShardedQuakeEngine(mesh, EngineConfig(
         k=k, nprobe=24, part_axes=("pod", "data"),
         scan_impl="union_pallas", storage_dtype="int8"))
-    ss8 = eng8.shard_snapshot(IndexSnapshot.from_index(idx))
+    ss8 = eng8.shard_snapshot(
+        IndexSnapshot.from_index(idx, layout=eng8.layout))
     d_8, i_8 = eng8.search_fixed(qs, ss8)
     rec_8 = np.mean([len(set(np.asarray(i_8[r]).tolist())
                          & set(gt[r].tolist())) / k for r in range(B)])

@@ -20,7 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..core.distributed import EngineConfig, IndexSnapshot, ShardedQuakeEngine
+from ..core.distributed import EngineConfig, ShardedQuakeEngine
+from ..core.snapshot import IndexSnapshot
 from ..kernels import ref
 from .base import SDS, ArchSpec, Lowering, dp_axes_for, register
 

@@ -17,7 +17,7 @@ top-down, running APS at every level; the items returned by APS at level
 ``l>0`` are exactly the candidate partitions (plus centroid distances) for
 level ``l-1``.
 
-The compiled, mesh-sharded engine (``distributed.ShardedIndexView``) consumes
+The compiled, mesh-sharded engine (``distributed.ShardedQuakeEngine``) consumes
 snapshots of this structure.
 """
 from __future__ import annotations
@@ -191,24 +191,27 @@ class QuakeIndex:
         if level_sizes is None:
             p0 = num_partitions or max(1, int(round(math.sqrt(n))))
             level_sizes = (p0,)
-        idx._max_norm_sq = max(float(np.max(np.sum(
-            x.astype(np.float64) ** 2, axis=1), initial=0.0)), 1e-12)
+        # the largest squared norm, in row blocks (no float64 copy of x)
+        blk = 1 << 16
+        idx._max_norm_sq = max(max((float(np.max(np.sum(
+            x[i:i + blk].astype(np.float64) ** 2, axis=1), initial=0.0))
+            for i in range(0, n, blk)), default=0.0), 1e-12)
 
-        # level 0
+        # level 0: the rows grouped by partition in one stable sort, so
+        # each partition keeps the rows in their order
         p0 = min(level_sizes[0], n)
         cents, assign = kmeans.kmeans(x, p0, iters=kmeans_iters,
                                       seed=idx.config.seed)
-        vectors, vids = [], []
-        for j in range(p0):
-            sel = assign == j
-            vectors.append(np.ascontiguousarray(x[sel]))
-            vids.append(ids[sel].astype(np.int64))
+        order = np.argsort(assign, kind="stable")
+        bounds = np.searchsorted(assign[order], np.arange(p0 + 1))
+        xs, ids_s = x[order], ids[order].astype(np.int64)
+        vectors = [xs[a:b] for a, b in zip(bounds[:-1], bounds[1:])]
+        vids = [ids_s[a:b] for a, b in zip(bounds[:-1], bounds[1:])]
         lvl0 = Level(centroids=cents, vectors=vectors, ids=vids,
                      sqnorms=[np.sum(v.astype(np.float64) ** 2, axis=1)
                               .astype(np.float32) for v in vectors])
         idx.levels.append(lvl0)
-        for ext, j in zip(ids, assign):
-            idx.id_map[int(ext)] = int(j)
+        idx.id_map.update(zip(ids.tolist(), assign.tolist()))
 
         # upper levels: cluster the centroids of the level below
         for p_l in level_sizes[1:]:
@@ -475,6 +478,7 @@ class QuakeIndex:
     _CALIB_QUERIES = 64    # leave-one-out sample of resident vectors
     _CALIB_K = 10          # neighbours the calibration counts
     _CALIB_DIMS = (256, 64, 16, 4)
+    _CALIB_RUN = 8192      # candidate columns sorted at once
 
     def set_aps_model(self, f_m: float, geometry_dim: int) -> None:
         """Set APS's candidate fraction and cap dimension (what
@@ -516,21 +520,29 @@ class QuakeIndex:
                       for p, f in zip(part, flat)])
         self_ids = np.asarray([lvl0.ids[p][f - starts[p]]
                                for p, f in zip(part, flat)])
-        # exact top-(k+1) over every partition, streamed
+        # exact top-(k+1) over every partition, streamed in runs of
+        # partitions: a stable sort keeps ties in partition order
         best_d = np.full((nq, 0), np.inf)
         best_i = np.full((nq, 0), -1, dtype=np.int64)
-        for j in range(lvl0.num_partitions):
-            if not sizes[j]:
-                continue
-            d = -(q @ lvl0.vectors[j].T).astype(np.float64)
-            if cfg.metric == "l2":
-                d = 2.0 * d + lvl0.sqnorms[j][None, :]
-            d = np.concatenate([best_d, d], axis=1)
-            i = np.concatenate([best_i, np.broadcast_to(
-                lvl0.ids[j], (nq, len(lvl0.ids[j])))], axis=1)
+        run_d, run_i, run_n = [best_d], [best_i], 0
+        for j in range(lvl0.num_partitions + 1):
+            if j < lvl0.num_partitions:
+                if not sizes[j]:
+                    continue
+                d = -(q @ lvl0.vectors[j].T).astype(np.float64)
+                if cfg.metric == "l2":
+                    d = 2.0 * d + lvl0.sqnorms[j][None, :]
+                run_d.append(d)
+                run_i.append(np.broadcast_to(lvl0.ids[j], d.shape))
+                run_n += d.shape[1]
+                if run_n < self._CALIB_RUN:
+                    continue
+            d = np.concatenate(run_d, axis=1)
+            i = np.concatenate(run_i, axis=1)
             keep = np.argsort(d, axis=1, kind="stable")[:, :k + 1]
             best_d = np.take_along_axis(d, keep, axis=1)
             best_i = np.take_along_axis(i, keep, axis=1)
+            run_d, run_i, run_n = [best_d], [best_i], 0
         truth = [set([i for i in row if i != s][:k])
                  for row, s in zip(best_i.tolist(), self_ids.tolist())]
 

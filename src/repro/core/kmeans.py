@@ -83,9 +83,11 @@ def kmeans(x: np.ndarray, k: int, iters: int = 10, seed: int = 0,
 
     c, assign, _ = _lloyd(jnp.asarray(xp), jnp.asarray(mask),
                           jnp.asarray(init_c), jnp.ones(k, bool), k, iters)
-    # np.array (not asarray): jax buffers are read-only; callers mutate.
-    # slice on host: slicing the device array would compile a new program
-    # for every distinct n (one per maintenance split on the chip)
+    # one pull for both; np.array (not asarray): jax buffers are
+    # read-only and callers mutate.  Slice on host: slicing the device
+    # array would compile a new program for every distinct n (one per
+    # maintenance split on the chip)
+    c, assign = jax.device_get((c, assign))
     return np.array(c), np.array(assign)[:n]
 
 

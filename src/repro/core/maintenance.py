@@ -181,7 +181,7 @@ def fit_to_capacity(idx: QuakeIndex, headroom: float) -> Optional[int]:
     largest partition) unless ``config.snapshot_capacity`` fixes it, in
     which case partitions larger than it are first split to
     ``capacity / headroom`` (``split_to_fit``)."""
-    from .distributed import IndexSnapshot  # late: avoid import cycle
+    from .snapshot import IndexSnapshot  # late: avoid import cycle
     fixed = idx.config.snapshot_capacity
     if not fixed:
         return None

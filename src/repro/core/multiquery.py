@@ -46,8 +46,10 @@ Architecture (see ``docs/batched_execution.md``):
 
 Single-query search is the B=1 case of the same executor
 (``per_query_search`` below, and ``QuakeIndex.search_batch`` with one row);
-the mesh-sharded engine shares the same planner through
-``ShardedQuakeEngine.search_batch`` (plan on host, pack+scan per shard).
+a partition-sharded snapshot (``layout``, below) runs the same plans with
+a per-shard pack and scan and an all-gather merge; the mesh-sharded
+engine's ``ShardedQuakeEngine.search_batch`` is this executor over its
+mesh.
 
 The executor serves a cached ``IndexSnapshot`` of the dynamic index
 (copy-on-write semantics, paper §8.2), kept coherent through the index's
@@ -67,10 +69,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
+
 from ..kernels import ops
 from ..kernels.ref import MASK_DIST
+from ..obs.tracing import NO_SPAN, span
 from . import aps as aps_mod
 from .index import QuakeIndex
+from .snapshot import (IndexSnapshot, PartitionLayout, merge_shards,
+                       scatter_rows)
 
 STORAGE_DTYPES = ("f32", "bf16", "int8")
 
@@ -973,19 +981,20 @@ def _batch_rho_fn(index: QuakeIndex, q: np.ndarray):
         max_norm_sq=m2)
 
 
-def _check_fits_device(p: int, s_cap: int, d: int, largest: int) -> None:
+def _check_fits_device(p: int, s_cap: int, d: int, largest: int,
+                       shards: int = 1) -> None:
     """Refuse, before staging it, a dense snapshot larger than the
-    device's memory.  The block is staged in f32 whatever the storage
-    dtype."""
+    devices' memory (each of ``shards`` devices holds ``p / shards``
+    rows).  The block is staged in f32 whatever the storage dtype."""
     stats = jax.devices()[0].memory_stats() or {}
     limit = stats.get("bytes_limit")
-    need = p * s_cap * d * 4
+    need = p * s_cap * d * 4 // shards
     if limit and need > limit:
         raise MemoryError(
             f"dense snapshot of {p} partitions x {s_cap} slots x d={d} "
-            f"(f32) needs {need / 1e9:.2f} GB, more than the device's "
-            f"{limit / 1e9:.2f} GB; the largest partition holds {largest} "
-            f"vectors")
+            f"(f32) over {shards} device(s) needs {need / 1e9:.2f} GB on "
+            f"each, more than the device's {limit / 1e9:.2f} GB; the "
+            f"largest partition holds {largest} vectors")
 
 
 class BatchedSearchExecutor:
@@ -1008,6 +1017,14 @@ class BatchedSearchExecutor:
     residual SQ8 through ``scan_selected_topk_q8``, 4x less traffic;
     content deltas would need requantization, so any journal delta forces
     a full rebuild — the same policy as the sharded engine).
+
+    ``layout`` (``snapshot.PartitionLayout``) shards the snapshot's
+    partition axis over a mesh, round-robin (paper §6): each round packs,
+    scans and keeps a top-k per shard inside one ``shard_map`` and merges
+    the shards' lists over the mesh.  The host mirrors ``_flat_ids`` and
+    ``_sizes`` follow the physical rows, so a flat scan index is one
+    gather away from its id.  One shard (the default) is today's
+    single-device snapshot and programs: no ``shard_map``, no collective.
     """
 
     def __init__(self, index: QuakeIndex, impl: str = "auto",
@@ -1018,13 +1035,17 @@ class BatchedSearchExecutor:
                  planner: str = "vectorized",
                  int8_rerank: bool = True,
                  rounds: Optional[int] = None,
-                 part_bucket: int = 1):
+                 part_bucket: int = 1,
+                 layout: Optional[PartitionLayout] = None):
         if storage_dtype not in STORAGE_DTYPES:
             raise ValueError(f"storage_dtype must be one of "
                              f"{STORAGE_DTYPES}, got {storage_dtype!r}")
         self.index = index
         self.impl = impl
         self.u_bucket = u_bucket
+        self.layout = layout or PartitionLayout()
+        self.shards = self.layout.shards
+        self._round_fns = {}     # static shape -> jitted sharded round
         self.part_bucket = max(part_bucket, 1)  # snapshot partition-count
                                  # rounding: a maintenance split/merge that
                                  # stays within the bucket keeps every
@@ -1052,8 +1073,8 @@ class BatchedSearchExecutor:
         self._snap = None
         self._key = None         # fingerprint the snapshot reflects
         self._valid = None       # (P, S_cap) bool, device
-        self._flat_ids = None    # (P*S_cap,) host
-        self._sizes = None       # (P,) host
+        self._flat_ids = None    # (P*S_cap,) host, physical row order
+        self._sizes = None       # (P,) host, physical row order
         self.planner_cache = PlannerCache(index)  # centroid norms +
                                  # calibrated radii, fingerprint-keyed
                                  # (refreshed with the snapshot)
@@ -1086,7 +1107,6 @@ class BatchedSearchExecutor:
         operand shape, and therefore the compiled scans, alive across
         maintenance epochs."""
         import math as _math
-        from .distributed import IndexSnapshot  # late: avoid import cycle
         from .maintenance import fit_to_capacity
         fixed = fit_to_capacity(self.index, self.headroom)
         lvl0 = self.index.levels[0]
@@ -1108,14 +1128,21 @@ class BatchedSearchExecutor:
             # the absolute-target usage here is only sound while the
             # target covers the live count (ceil(p/pad_to) == 1)
             pad_to = max(pad_to, lvl0.num_partitions)
+        # every shard holds as many rows
+        pad_to = -(-pad_to // self.shards) * self.shards
         _check_fits_device(-(-lvl0.num_partitions // pad_to) * pad_to,
                            IndexSnapshot.align_capacity(cap),
-                           self.index.dim, max_sz)
+                           self.index.dim, max_sz, self.shards)
         # release the old snapshot before staging the new one: at chip
         # scale the two do not fit in device memory together
         self._snap = self._valid = None
-        snap = IndexSnapshot.from_index(self.index, capacity=cap,
-                                        pad_partitions_to=pad_to)
+        with span("snapshot.stage") as sp:
+            if sp is not NO_SPAN:
+                sp.set_metadata(shards=self.shards,
+                                partitions=lvl0.num_partitions)
+            snap = IndexSnapshot.from_index(self.index, capacity=cap,
+                                            pad_partitions_to=pad_to,
+                                            layout=self.layout)
         self._valid = snap.ids >= 0
         self._flat_ids = np.array(snap.ids).reshape(-1)
         self._sizes = np.array(snap.sizes)
@@ -1137,7 +1164,8 @@ class BatchedSearchExecutor:
     def footprint(self) -> dict:
         """Device footprint of the cached snapshot: the dense
         ``(P, S_cap, d)`` block next to the live vectors it holds (the
-        rest is slot and partition padding) and how it was kept fresh."""
+        rest is slot and partition padding), its bytes on each device,
+        and how it was kept fresh."""
         snap = self._snap
         if snap is None:
             return {}
@@ -1146,6 +1174,10 @@ class BatchedSearchExecutor:
         return {"partitions": int(p), "capacity": int(cap), "dim": int(d),
                 "dtype": str(snap.data.dtype),
                 "device_bytes": int(snap.data.nbytes),
+                "shards": self.shards,
+                "device_bytes_per_device": [
+                    int(sh.data.nbytes)
+                    for sh in snap.data.addressable_shards],
                 "live_vectors": live,
                 "live_bytes": live * int(d) * snap.data.dtype.itemsize,
                 "largest_partition": int(self._sizes.max(initial=0)),
@@ -1158,7 +1190,6 @@ class BatchedSearchExecutor:
         overflow, dirty set too large, or int8 storage — residual codes
         would need requantizing) — caller falls back to ``refresh``.
         """
-        from .distributed import IndexSnapshot  # late: avoid import cycle
         if self._snap.scales is not None:
             return False          # int8: requantize via full rebuild
         idx = self.index
@@ -1182,13 +1213,13 @@ class BatchedSearchExecutor:
             # donate: the executor owns its cached snapshot exclusively,
             # so the patch updates the device buffers in place — refresh
             # cost is O(dirty rows), not O(index)
-            self._snap = self._snap.apply_delta(patch, donate=True)
+            self._snap = self._snap.apply_delta(patch, donate=True,
+                                                layout=self.layout)
         except ValueError:
             return False
-        from .distributed import _scatter_rows_donated
-        sel = patch.rows
-        self._valid = _scatter_rows_donated(
-            self._valid, jnp.asarray(sel), jnp.asarray(patch.ids >= 0))
+        sel = self._rows(patch.rows)
+        self._valid = scatter_rows(self.layout, self._valid, sel,
+                                   patch.ids >= 0, donate=True)
         self._flat_ids.reshape(self._snap.num_partitions, cap)[sel] = \
             patch.ids
         self._sizes[sel] = patch.sizes
@@ -1196,6 +1227,12 @@ class BatchedSearchExecutor:
         self._key = self._fingerprint()
         self.delta_refreshes += 1
         return True
+
+    def _rows(self, j, p: Optional[int] = None):
+        """Physical snapshot rows of partitions ``j`` (``j`` itself on
+        one shard)."""
+        return self.layout.rows(
+            j, self._snap.num_partitions if p is None else p)
 
     def _rerank_exact(self, q: np.ndarray, flat: np.ndarray, k: int
                       ) -> Tuple[np.ndarray, np.ndarray]:
@@ -1275,21 +1312,30 @@ class BatchedSearchExecutor:
             else jnp.asarray(plan.sel.astype(np.int32))
         qmask_dev = plan.qmask_dev if plan.qmask_dev is not None \
             else jnp.asarray(plan.qmask)
-        if snap.scales is not None:     # int8 residual codes
-            rerank = self.int8_rerank and self._host_f32 is not None
-            k_scan = 2 * k if rerank else k
+        rerank = (snap.scales is not None and self.int8_rerank
+                  and self._host_f32 is not None)
+        k_scan = 2 * k if rerank else k
+        if self.layout.mapped:
+            # the plan as one round: each query takes its packed columns
+            kept = plan.sel[:plan.n_real]
+            take = np.asarray(plan.qmask[:, :plan.n_real])
+            seq = np.broadcast_to(kept.astype(np.int32), take.shape)
+            dd, flat, _ = self.scan_probe_round(
+                jnp.asarray(q), jnp.asarray(seq), take, kept, k_scan,
+                snap=snap, impl=impl)
+        elif snap.scales is not None:     # int8 residual codes
             dd, flat = ops.scan_selected_topk_q8(
                 jnp.asarray(q), snap.data, snap.scales, self._valid,
                 sel_dev, qmask_dev, k_scan,
                 metric=self.index.config.metric, centroids=snap.centroids)
-            if rerank:
-                # quakecheck: allow-sync(int8 rerank gathers from the host f32 mirror)
-                dd, flat = self._rerank_exact(q, np.asarray(flat), k)
         else:
             dd, flat = ops.scan_selected_topk(
                 jnp.asarray(q), snap.data, self._valid,
                 sel_dev, qmask_dev, k,
                 metric=self.index.config.metric, impl=impl or self.impl)
+        if rerank:
+            # quakecheck: allow-sync(int8 rerank gathers from the host f32 mirror)
+            dd, flat = self._rerank_exact(q, np.asarray(flat), k)
         # quakecheck: allow-sync(result boundary: BatchResult is a host contract)
         dd = np.asarray(dd, dtype=np.float64)
         flat = np.asarray(flat)  # quakecheck: allow-sync(result boundary)
@@ -1297,7 +1343,7 @@ class BatchedSearchExecutor:
                        self._flat_ids[np.maximum(flat, 0)], -1)
         dd = np.where(dd >= MASK_DIST, np.inf, dd)
 
-        sizes_sel = self._sizes[plan.sel[:plan.n_real]]
+        sizes_sel = self._sizes[self._rows(plan.sel[:plan.n_real])]
         return BatchResult(
             ids=ids.astype(np.int64), dists=dd,
             partitions_scanned=int(plan.n_real),
@@ -1349,28 +1395,53 @@ class BatchedSearchExecutor:
         once).
         """
         snap = self.snapshot() if snap is None else snap
+        kept = np.asarray(kept, dtype=np.int64)
+        p_snap = int(snap.num_partitions)
+        # stats from the host-side plan data the caller already holds —
+        # the packed plan itself never leaves the device
+        sizes_kept = self._sizes[self._rows(kept, p_snap)]
+        vectors = int(sizes_kept.sum())
+        if seq_host is not None:
+            comparisons = int(self._sizes[self._rows(seq_host[take],
+                                                     p_snap)].sum())
+        else:
+            comparisons = vectors
+        u_pad, n_real = self.round_width(kept, u_pow2)
+        st = {"partitions": int(max(len(kept), 1)), "vectors": vectors,
+              "comparisons": comparisons, "shard_vectors_max": vectors}
+        if self.layout.mapped:
+            shard = kept % self.shards
+            st["shard_vectors_max"] = int(np.bincount(
+                shard, weights=sizes_kept, minlength=self.shards).max())
+            per_shard = np.bincount(shard, minlength=self.shards)
+            q8 = snap.scales is not None
+            take = np.asarray(take)
+            b = take.shape[0]
+            pad = -b % self.layout.batch_shards   # rows split evenly
+            if pad:
+                q_dev = jnp.pad(q_dev, ((0, pad), (0, 0)))
+                seq_dev = jnp.pad(seq_dev, ((0, pad), (0, 0)))
+                take = np.pad(take, ((0, pad), (0, 0)))
+            args = (q_dev, seq_dev, jnp.asarray(take),
+                    self.layout.put(per_shard.astype(np.int32)),
+                    snap.data, self._valid)
+            if q8:
+                args += (snap.scales, snap.centroids)
+            d, flat = self._sharded_round(u_pad, k_keep, impl or self.impl,
+                                          q8)(*args)
+            if pad:
+                d, flat = d[:b], flat[:b]
+            return d, flat, st
         # pack against the snapshot's (padded) partition count: stable
         # across rebuilds when part_bucket > 1, so the jitted pack
         # survives maintenance epochs
-        p = max(self.index.levels[0].num_partitions,
-                int(snap.num_partitions))
+        p = max(self.index.levels[0].num_partitions, p_snap)
         prio0 = jnp.zeros((p,), jnp.int32)   # uncapped: no anchor boost
-        n_real = max(len(kept), 1)
-        u_pad = self.union_pad(n_real, u_pow2)
         # pack + inert-tail masking on device (no host round trip; the
         # dynamic n_real scalar shares one executable across round sizes)
         sel_dev, qmask_dev = ops.pack_round_masked(
-            seq_dev, jnp.asarray(take), prio0, n_real, p=p, u_pad=u_pad)
-        # stats from the host-side plan data the caller already holds —
-        # the packed plan itself never leaves the device
-        sizes_kept = self._sizes[np.asarray(kept, dtype=np.int64)]
-        vectors = int(sizes_kept.sum())
-        if seq_host is not None:
-            comparisons = int(self._sizes[seq_host[take]].sum())
-        else:
-            comparisons = vectors
-        st = {"partitions": int(n_real), "vectors": vectors,
-              "comparisons": comparisons}
+            seq_dev, jnp.asarray(take), prio0, max(n_real, 1), p=p,
+            u_pad=u_pad)
         if snap.scales is not None:
             d, flat = ops.scan_selected_topk_q8(
                 q_dev, snap.data, snap.scales, self._valid,
@@ -1388,6 +1459,65 @@ class BatchedSearchExecutor:
                 "k": k_keep, "metric": self.index.config.metric,
                 "impl": ops._resolve(impl or self.impl)}
         return d, flat, st
+
+    def round_width(self, kept, u_pow2: bool = False) -> Tuple[int, int]:
+        """What a round over the union partitions ``kept`` scans on each
+        shard: (its padded union width, the largest shard's count of
+        ``kept``).  One shard: the whole union."""
+        if self.shards == 1:
+            n = len(kept)
+        else:
+            n = int(np.bincount(np.asarray(kept, dtype=np.int64)
+                                % self.shards,
+                                minlength=self.shards).max())
+        return self.union_pad(n, u_pow2), n
+
+    def _sharded_round(self, u_pad: int, k: int, impl: str, q8: bool):
+        """The jitted round over a sharded snapshot: one ``shard_map`` in
+        which every shard masks the round's cells to its own partitions
+        (``j % shards``), packs them over its local rows (``j //
+        shards``) at the static width ``u_pad`` with its own live count,
+        scans them with the one-chip kernel, offsets its flat indices to
+        physical rows and merges the shards' top-``k`` lists over the
+        mesh.  A layout with a ``batch_axis`` splits the query rows over
+        it (each replica of the partition shards takes its share of the
+        batch).  Cached per static shape (the snapshot's rows reshape it
+        through jit's own cache)."""
+        key = (u_pad, k, impl, q8)
+        fn = self._round_fns.get(key)
+        if fn is not None:
+            return fn
+        lay = self.layout
+        n = lay.shards
+        metric = self.index.config.metric
+
+        def local(q, seq, take, n_real, data, valid, *q8_ops):
+            i = jax.lax.axis_index(lay.axes)
+            p_loc, s_cap = data.shape[0], data.shape[1]
+            sel, qmask = ops.pack_round_masked(
+                seq // n, take & (seq % n == i),
+                jnp.zeros((p_loc,), jnp.int32), n_real[0], p=p_loc,
+                u_pad=u_pad)
+            if q8_ops:
+                scales, cents = q8_ops
+                d, flat = ops.scan_selected_topk_q8(
+                    q, data, scales, valid, sel, qmask, k, metric=metric,
+                    centroids=cents)
+            else:
+                d, flat = ops.scan_selected_topk(
+                    q, data, valid, sel, qmask, k, metric=metric, impl=impl)
+            flat = jnp.where(flat >= 0, flat + i * (p_loc * s_cap), -1)
+            return merge_shards(d, flat, k, lay.axes)
+
+        part = P(lay.axes)
+        rows = P(lay.batch_axis) if lay.batch_shards > 1 else P()
+        fn = jax.jit(shard_map(
+            local, mesh=lay.mesh,
+            in_specs=(rows, rows, rows, part, part, part)
+            + ((part, part) if q8 else ()),
+            out_specs=(rows, rows), check_vma=False))
+        self._round_fns[key] = fn
+        return fn
 
     def _search_rounds(self, q: np.ndarray, k: int, target: float,
                        rounds: Optional[int],

@@ -64,6 +64,7 @@ from ..sanitize import TrackedLock, note_guarded, observability_counters
 from . import aps as aps_mod
 from . import multiquery as mq
 from .cost_model import LatencyModel
+from .snapshot import partition_layout
 from .durability import DurabilityManager, RecoveryReport, recover_index
 from .index import QuakeIndex
 from .maintenance import (Maintainer, MaintenanceReport, checkpoint_index,
@@ -149,7 +150,12 @@ class ServingConfig:
                                        # write barriers freeze the index
                                        # within an epoch, so the live
                                        # buffers are snapshot-coherent);
-                                       # "auto" picks host off-TPU
+                                       # "auto" picks host off-TPU,
+                                       # device when sharded
+    part_shards: int = 1               # devices the snapshot's partition
+                                       # axis is sharded over, round-robin
+                                       # (jax.devices()[:part_shards] in a
+                                       # ("data",) mesh; device backend)
     # --- result cache (0 entries disables) ---
     cache_entries: int = 0
     cache_bits: int = 0                # sign-LSH key bits; 0 = exact bytes
@@ -301,6 +307,12 @@ class ServingConfig:
         if self.calibration_window < 1:
             raise ValueError(f"calibration_window must be >= 1 "
                              f"(got {self.calibration_window})")
+        if self.part_shards < 1:
+            raise ValueError(f"part_shards must be >= 1 "
+                             f"(got {self.part_shards})")
+        if self.part_shards > 1 and self.scan_backend == "host":
+            raise ValueError("a sharded snapshot (part_shards > 1) is "
+                             "scanned on the device backend")
 
 
 @dataclass
@@ -736,7 +748,7 @@ def host_scan_round(index: QuakeIndex, q: np.ndarray, seq: np.ndarray,
     out_d = np.take_along_axis(out_d, order, axis=1)
     out_i = np.take_along_axis(out_i, order, axis=1)
     st = {"partitions": int(len(kept)), "vectors": int(vectors),
-          "comparisons": int(comparisons)}
+          "comparisons": int(comparisons), "shard_vectors_max": int(vectors)}
     return out_d, out_i, st
 
 
@@ -837,7 +849,7 @@ class RoundScheduler:
         if scan_backend == "auto":
             import jax
             scan_backend = ("device" if jax.default_backend() == "tpu"
-                            else "host")
+                            or executor.shards > 1 else "host")
         if scan_backend not in ("host", "device"):
             raise ValueError(f"scan_backend must be host/device/auto, "
                              f"got {scan_backend!r}")
@@ -877,6 +889,7 @@ class RoundScheduler:
         self._obs_waits: List[float] = []
         self._obs_parts = 0
         self._obs_vecs = 0
+        self._obs_shard_vmax = 0
         self._obs_one_window = 0
         self._obs_windowed = 0
         self._obs_rounds: List[dict] = []
@@ -1017,14 +1030,18 @@ class RoundScheduler:
             return 0
         with self._lock:
             self._bind_snapshot(self.ex.snapshot())
-            p = self.index.levels[0].num_partitions
+            # a sharded round's width is its largest shard's union: warm
+            # it with the partitions of one shard (every one on one chip)
+            ids = np.arange(0, self.index.levels[0].num_partitions,
+                            self.ex.shards)
             m = mq._aps_candidate_budget(self.index)
             b = self.b_bucket
-            # rows of consecutive partition ids (wrapping at p): the first
-            # n cells in row order are n distinct partitions
-            seq = (np.arange(b)[:, None] * m + np.arange(m)[None, :]) % p
+            # rows of consecutive ids (wrapping): the first n cells in row
+            # order are n distinct partitions
+            seq = ids[(np.arange(b)[:, None] * m + np.arange(m)[None, :])
+                      % len(ids)]
             q = np.zeros((b, self.index.dim), np.float32)
-            most = min(p, b * m)
+            most = min(len(ids), b * m)
             top = self.ex.union_pad(most, u_pow2=True)
             u = self.ex.union_pad(1, u_pow2=True)
             widths = 0
@@ -1032,7 +1049,7 @@ class RoundScheduler:
                 n = min(u, most)
                 take = np.zeros((b, m), dtype=bool)
                 take.flat[:n] = True
-                self._scan_once(q, seq, take, np.arange(n), [])
+                self._scan_once(q, seq, take, ids[:n], [])
                 widths += 1
                 if u >= top:
                     return widths
@@ -1080,7 +1097,7 @@ class RoundScheduler:
         kept = np.unique(seq_mat[base])
         if sp is not NO_SPAN:
             sp.set_metadata(rows=b, b_pad=self._row_pad(b), union=len(kept),
-                            u_pad=self.ex.union_pad(len(kept), u_pow2=True))
+                            u_pad=self.ex.round_width(kept, u_pow2=True)[0])
         p = self.index.levels[0].num_partitions
         in_union = np.zeros(max(int(seq_mat.max()) + 1, p), dtype=bool)
         in_union[kept] = True
@@ -1190,6 +1207,7 @@ class RoundScheduler:
         self._obs_waits.append(wait_s)
         self._obs_parts += int(st["partitions"])
         self._obs_vecs += int(st["vectors"])
+        self._obs_shard_vmax += int(st["shard_vectors_max"])
         # predicted-vs-observed scan cost, sampled every 4th round
         # (first round always): ``predict_scan_ns`` over the folded
         # sizes is a numpy pass per call, and roughly-one-sample-per-
@@ -1238,8 +1256,10 @@ class RoundScheduler:
             seq_pad, take_pad = seq_mat, take
         with _span(self.obs, "scan.dispatch") as sp:
             if sp is not NO_SPAN:
-                sp.set_metadata(b_pad=b_pad, u_pad=self.ex.union_pad(
-                    len(kept), u_pow2=True))
+                u_pad, shard_union = self.ex.round_width(kept, u_pow2=True)
+                sp.set_metadata(b_pad=b_pad, u_pad=u_pad,
+                                shards=self.ex.shards,
+                                shard_union=shard_union)
             d, flat, st = self.ex.scan_probe_round(
                 jnp.asarray(q_pad), jnp.asarray(seq_pad.astype(np.int32)),
                 take_pad, kept, self._k_keep, snap=self._snap, u_pow2=True,
@@ -1398,16 +1418,19 @@ class RoundScheduler:
             flushes, self._obs_flushes = self._obs_flushes, []
             served, self._obs_served = self._obs_served, []
             parts, vecs = self._obs_parts, self._obs_vecs
+            vmax = self._obs_shard_vmax
             one, wnd = self._obs_one_window, self._obs_windowed
             self._obs_parts = 0
             self._obs_vecs = 0
+            self._obs_shard_vmax = 0
             self._obs_one_window = 0
             self._obs_windowed = 0
         counters, observations = {}, {}
         if walls:
             counters = {"scheduler.rounds": len(walls),
                         "scheduler.partitions_streamed": parts,
-                        "scheduler.vectors_streamed": vecs}
+                        "scheduler.vectors_streamed": vecs,
+                        "scan.shard_vectors_max": vmax}
             observations = {"scheduler.round_wall_s": walls,
                             "scan.wait_s": waits}
         if flushes:
@@ -1524,7 +1547,8 @@ class ServingRuntime:
         self.executor = mq.BatchedSearchExecutor(
             index, impl=self.cfg.impl, storage_dtype=self.cfg.storage_dtype,
             planner=self.cfg.planner, rounds=self.cfg.rounds,
-            part_bucket=32)   # shape-stable snapshots across maintenance
+            part_bucket=32,   # shape-stable snapshots across maintenance
+            layout=partition_layout(self.cfg.part_shards))
         self.cache = (ResultCache(self.cfg.cache_entries,
                                   bits=self.cfg.cache_bits,
                                   tol=self.cfg.cache_tol,
@@ -1552,6 +1576,8 @@ class ServingRuntime:
             trace_capacity=self.cfg.trace_capacity,
             calibration_window=self.cfg.calibration_window)
             if self.cfg.metrics else None)
+        if self.obs is not None:
+            self.obs.metrics.set_gauge("scan.shards", self.executor.shards)
         self.scheduler = RoundScheduler(
             self.executor, self.cfg.k, self.target,
             rounds=self.cfg.rounds, early_exit=self.cfg.early_exit,

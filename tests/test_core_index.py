@@ -135,3 +135,38 @@ def test_calibrate_aps_fits_model_where_it_stops_early():
     fitted = recall()
     idx.set_aps_model(idx.config.f_m, idx.model_dim)
     assert fitted >= 0.9 > recall()
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+def test_build_groups_rows_in_their_order(metric):
+    """The build's one-sort grouping: every row lands in exactly one
+    partition, each partition holds its rows in row order with their ids
+    and norms, and the largest squared norm is the whole corpus's."""
+    ds = datasets.clustered(3000, 16, n_clusters=12, seed=3, metric=metric)
+    x = ds.vectors
+    ids = np.arange(len(x)) * 5 + 11
+    idx = QuakeIndex.build(x, ids, config=QuakeConfig(metric=metric),
+                           kmeans_iters=3)
+    lvl0 = idx.levels[0]
+    seen = np.concatenate(lvl0.ids)
+    assert np.array_equal(np.sort(seen), ids)
+    for j in range(lvl0.num_partitions):
+        rows = (lvl0.ids[j] - 11) // 5
+        assert np.all(np.diff(rows) > 0)
+        assert np.array_equal(lvl0.vectors[j], x[rows])
+        assert np.array_equal(lvl0.sqnorms[j], np.sum(
+            x[rows].astype(np.float64) ** 2, axis=1).astype(np.float32))
+        assert all(idx.id_map[int(e)] == j for e in lvl0.ids[j])
+    assert idx._max_norm_sq == float(np.max(np.sum(
+        x.astype(np.float64) ** 2, axis=1)))
+
+
+def test_calibrate_aps_runs_match_one_partition_at_a_time(clustered,
+                                                          monkeypatch):
+    """Sorting the exact candidates in runs of partitions picks the same
+    neighbours, and so fits the same model, as sorting after each
+    partition."""
+    idx = QuakeIndex.build(clustered.vectors, kmeans_iters=3)
+    runs = idx.calibrate_aps()
+    monkeypatch.setattr(QuakeIndex, "_CALIB_RUN", 1)
+    assert idx.calibrate_aps() == runs

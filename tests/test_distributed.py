@@ -199,7 +199,8 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
                 ("pod", "data", "model"))
     eng = ShardedQuakeEngine(mesh, EngineConfig(
         k=10, nprobe=8, part_axes=("pod", "data")))
-    snap = IndexSnapshot.from_index(idx, pad_partitions_to=eng.n_part_shards)
+    snap = IndexSnapshot.from_index(idx, pad_partitions_to=eng.n_part_shards,
+                                    layout=eng.layout)
     ss = eng.shard_snapshot(snap)
     q = jnp.asarray(datasets.queries_near(ds, 8, seed=1))
     gt = ds.ground_truth(np.asarray(q), 10)
@@ -212,12 +213,31 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
                          & set(gt[r].tolist())) / 10 for r in range(8)])
     assert rec_a >= 0.8, rec_a
 
-    # planner-driven multi-query entry on a real 2x2x2 mesh: the (B, P)
-    # probe matrix shards over batch x partition axes
+    # planner-driven multi-query entry on a real 2x2x2 mesh: each round
+    # shards its query rows over "model" and its partitions over
+    # ("pod", "data")
     r_b = eng.search_batch(idx, np.asarray(q), 10, nprobe=8)
     rec_b = np.mean([len(set(r_b.ids[r].tolist())
                          & set(gt[r].tolist())) / 10 for r in range(8)])
     assert rec_b >= 0.8, rec_b
+    ex = eng.executor(idx)
+    kept = np.arange(ex.snapshot().num_partitions // 2)
+    take = np.ones((8, len(kept)), bool)
+    seq = jnp.asarray(np.broadcast_to(kept.astype(np.int32), take.shape))
+    d_r, f_r, _ = ex.scan_probe_round(q, seq, take, kept, 10)
+    assert d_r.sharding.spec == P("model"), d_r.sharding
+    d_7, f_7, _ = ex.scan_probe_round(q[:7], seq[:7], take[:7], kept, 10)
+    np.testing.assert_array_equal(np.asarray(f_7), np.asarray(f_r)[:7])
+    # a mesh of query replicas alone: one partition shard, eight batch
+    eng_q = ShardedQuakeEngine(
+        Mesh(np.array(jax.devices()).reshape(1, 1, 8),
+             ("pod", "data", "model")),
+        EngineConfig(k=10, nprobe=8, part_axes=("pod", "data")))
+    r_q = eng_q.search_batch(idx, np.asarray(q), 10, nprobe=8)
+    np.testing.assert_array_equal(r_q.ids, r_b.ids)
+    ex_q = eng_q.executor(idx)
+    d_q, _, _ = ex_q.scan_probe_round(q, seq, take, kept, 10)
+    assert d_q.sharding.spec == P("model"), d_q.sharding
 
     # elastic checkpoint: save replicated, restore sharded on a new mesh
     params = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}

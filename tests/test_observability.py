@@ -236,6 +236,8 @@ GOLDEN_KEYS = (
     "scheduler.vectors_streamed", "scheduler.round_wall_s.count",
     "scheduler.round_wall_s.p50",
     "scheduler.one_window_rows", "scheduler.windowed_rows",
+    # the snapshot's partition shards (one on one device)
+    "scan.shards", "scan.shard_vectors_max",
     # the served path's steps, on the runtime's clock
     "serving.engine_wait_s.count", "serving.flush_s.count",
     "planner.plan_s.count", "scan.wait_s.count",
@@ -483,9 +485,9 @@ PROGRAM_SPANS = ("serving.engine_wait", "serving.flush", "planner.plan",
                  "scheduler.fold", "serving.collect")
 
 
-def _profiled(log_dir, fn):
+def _profiled(log_dir, fn, names=PROGRAM_SPANS):
     """Run ``fn`` under the JAX profiler; returns its result and the
-    program spans recorded, per host thread:
+    spans of ``names`` recorded, per host thread:
     ``[[(name, start_ns, end_ns, stats), ...], ...]``."""
     import jax
     from jax.profiler import ProfileData
@@ -503,7 +505,7 @@ def _profiled(log_dir, fn):
         for line in plane.lines:
             evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
                     {k: v for k, v in e.stats})
-                   for e in line.events if e.name in PROGRAM_SPANS]
+                   for e in line.events if e.name in names]
             if evs:
                 threads.append(evs)
     return out, threads
@@ -526,6 +528,20 @@ def _serve_mixed(ds, metrics):
         rt.drain()
         rt.drain()                 # nothing queued, nothing in flight
     return rt, serve
+
+
+def test_snapshot_stage_span(ds, tmp_path):
+    """A full rebuild of the device snapshot (here at ``stage()``) is one
+    ``snapshot.stage`` span over the host padding and the device puts,
+    carrying the shards and the partitions staged."""
+    idx = build(ds)
+    rt = ServingRuntime(idx, serve_cfg(scan_backend="device", impl="jnp",
+                                       ticker=False))
+    _, threads = _profiled(tmp_path, rt.stage, names=("snapshot.stage",))
+    rt.close()
+    evs = [ev for t in threads for ev in t]
+    assert len(evs) == 1
+    assert evs[0][3] == {"shards": 1, "partitions": idx.num_partitions}
 
 
 def _inside(inner, outers):
@@ -562,7 +578,8 @@ def test_profiler_spans_nest_and_match_registry(ds, tmp_path):
             assert 1 <= st["rows"] <= st["b_pad"]
             assert 1 <= st["union"] <= st["u_pad"]
         for ev in by["scan.dispatch"]:
-            assert set(ev[3]) == {"b_pad", "u_pad"}
+            assert set(ev[3]) == {"b_pad", "u_pad", "shards", "shard_union"}
+            assert ev[3]["shards"] == 1
     # every query of the window left the queue in one served flush
     assert sum(ev[3]["n"] for evs in threads for ev in evs
                if ev[0] == "serving.flush") == 16

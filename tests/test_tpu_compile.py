@@ -107,3 +107,31 @@ def test_kmeans_assign_compiles(one_chip, no_compile_cache):
     txt = _compile(fn, one_chip, ((4096, D), jnp.float32),
                    ((1024, D), jnp.float32), ((1, 1024), jnp.float32))
     assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("u_pad", [8, 256])
+def test_sharded_round_compiles(u_pad, topo, no_compile_cache, monkeypatch):
+    """The served round over a snapshot sharded on a described four-chip
+    mesh (``ServingConfig.part_shards=4``): one program holding the
+    Mosaic scan kernel and the all-gather that merges the shards."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+    from repro.core import QuakeIndex
+    from repro.core.snapshot import PartitionLayout
+    from repro.core.multiquery import BatchedSearchExecutor
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)   # Mosaic, not interpret
+    x = np.random.default_rng(0).normal(size=(64, 8)).astype(np.float32)
+    lay = PartitionLayout(Mesh(np.array(topo.devices[:4]), ("data",)))
+    ex = BatchedSearchExecutor(QuakeIndex.build(x, num_partitions=4),
+                               layout=lay)
+    fn = ex._sharded_round(u_pad, 10, "pallas", False)
+    p, m, s = 4 * 256, 512, 1024
+    part, rep = (NamedSharding(lay.mesh, PS("data")),
+                 NamedSharding(lay.mesh, PS()))
+    args = [jax.ShapeDtypeStruct(sh, dt, sharding=sd) for sh, dt, sd in (
+        ((16, D), jnp.float32, rep), ((16, m), jnp.int32, rep),
+        ((16, m), jnp.bool_, rep), ((4,), jnp.int32, part),
+        ((p, s, D), jnp.float32, part), ((p, s), jnp.bool_, part))]
+    txt = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in txt and "all-gather" in txt
